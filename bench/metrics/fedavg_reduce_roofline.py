@@ -1,0 +1,28 @@
+"""fedavg_reduce_roofline: the share of its roofline that ``kernels/fedavg_reduce.py``'s kernels
+(_fedavg_kernel) reached in the traced window (%).
+
+Each call's least time is the larger of its FLOPs over the bf16 peak and
+its operand and result bytes over the HBM bandwidth, both counted once per
+call from the call's shapes in the compiled program; the share is the
+calls' least time over their traced time.  A kernel that re-reads its
+operands is counted once, so the share is a lower bound.
+"""
+from bench import counting
+
+KERNELS = ('_fedavg_kernel',)
+
+
+def read(ctx):
+    if ctx.trace is None:
+        return None
+    least = spent = 0.0
+    for inst, info in ctx.kernel_calls.items():
+        if info["kernel"] not in KERNELS:
+            continue
+        n = ctx.trace.call_counts.get(inst, 0)
+        least += n * counting.roofline_seconds(info["flops"], info["bytes"],
+                                               ctx.peaks)
+        spent += ctx.trace.call_seconds.get(inst, 0.0)
+    if spent <= 0.0:
+        return None
+    return 100.0 * least / spent

@@ -1,0 +1,8 @@
+"""idle_share.learning: the share of the traced window in which no
+operation ran on the device, in a learning cell (%)."""
+
+
+def read(ctx):
+    if ctx.trace is None or "rounds" not in ctx.counters:
+        return None
+    return 100.0 * ctx.trace.idle_share
