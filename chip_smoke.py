@@ -38,7 +38,6 @@ import json
 import os
 import re
 import sys
-import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 SRC = os.path.join(HERE, "src")
@@ -57,23 +56,6 @@ def fail(msg: str) -> None:
 def check(cond: bool, msg: str) -> None:
     if not cond:
         fail(msg)
-
-
-class CompileClock:
-    """Sums JAX's own trace + lower + backend-compile event durations."""
-
-    def __init__(self):
-        import jax
-        self.secs = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event: str, secs: float, **_):
-        if event.startswith("/jax/core/compile/"):
-            self.secs += secs
-
-    def lap(self) -> float:
-        secs, self.secs = self.secs, 0.0
-        return secs
 
 
 def tpu_kernels(lowered) -> list[str]:
@@ -99,7 +81,7 @@ def report(phase: str, dev, **fields) -> None:
 
 
 # ----------------------------------------------------------------- phase A --
-def phase_a(dev, clock: CompileClock) -> None:
+def phase_a(dev) -> None:
     import numpy as np
     from repro.fl import FLConfig, FLSimulation
     from repro.models.cnn import CNNConfig
@@ -116,16 +98,7 @@ def phase_a(dev, clock: CompileClock) -> None:
         pallas._carry(), pallas.round_idx, n_rounds=rounds))
     check("_fedavg_kernel" in kernels,
           f"phase A: fused step lowered no fedavg_reduce kernel: {kernels}")
-    clock.lap()
-    t0 = time.perf_counter()
     got = pallas.run(rounds, mode="fused")
-    first = time.perf_counter() - t0
-    compile_s = clock.lap()
-    t0 = time.perf_counter()
-    pallas.run(rounds, mode="fused")            # warm: same shapes
-    steady = time.perf_counter() - t0
-    warm_compile_s = clock.lap()
-
     want = sim("jax").run(rounds, mode="fused")
     for g, w in zip(got, want):
         check(g.t_round == w.t_round and g.n_selected == w.n_selected,
@@ -136,9 +109,7 @@ def phase_a(dev, clock: CompileClock) -> None:
           "phase A: non-finite accuracy")
     check(acc_diff <= ACC_TOL,
           f"phase A: |test_acc pallas - jnp| = {acc_diff} > {ACC_TOL}")
-    report("A", dev, kernels=",".join(kernels), compile_s=compile_s,
-           first_call_s=first, steady_rounds_per_s=rounds / steady,
-           warm_compile_s=warm_compile_s,
+    report("A", dev, kernels=",".join(kernels),
            final_acc=got[-1].test_acc, max_acc_diff=acc_diff)
 
 
@@ -162,7 +133,7 @@ def _fleet_problem(cfg):
     return stack_problems([prob])
 
 
-def phase_b(dev, clock: CompileClock) -> None:
+def phase_b(dev) -> None:
     import jax
     import numpy as np
     from repro.core import dagsa_jit
@@ -178,14 +149,7 @@ def phase_b(dev, clock: CompileClock) -> None:
                          backend=backend, user_chunk=USER_CHUNK,
                          channel_dtype="bf16")
 
-    clock.lap()
-    t0 = time.perf_counter()
     got = sweep("pallas")[0]
-    first = time.perf_counter() - t0
-    compile_s = clock.lap()
-    t0 = time.perf_counter()
-    sweep("pallas")
-    steady = time.perf_counter() - t0
     want = sweep("jax")[0]
     n_got, n_want = got["curves"]["n_selected"], want["curves"]["n_selected"]
     check(n_got == n_want, f"phase B sweep: n_selected pallas {n_got} != "
@@ -219,8 +183,7 @@ def phase_b(dev, clock: CompileClock) -> None:
     check(np.allclose(np.asarray(d_got.t_round), np.asarray(d_want.t_round),
                       rtol=T_RTOL, atol=0.0),
           f"phase B decision: t_round {d_got.t_round} vs {d_want.t_round}")
-    report("B", dev, kernels=",".join(kernels), compile_s=compile_s,
-           first_call_s=first, steady_rounds_per_s=rounds / steady,
+    report("B", dev, kernels=",".join(kernels),
            n_selected=n_got, t_round_s=t_got.tolist(),
            decision_selected=int(sel_got.sum()))
 
@@ -244,7 +207,7 @@ def _max_diff(a, b) -> float | None:
     return None if None in diffs else max(diffs, default=0.0)
 
 
-def four_chips(clock: CompileClock) -> None:
+def four_chips() -> None:
     import jax
     from repro.launch import shard_sweep, sweep
 
@@ -263,18 +226,13 @@ def four_chips(clock: CompileClock) -> None:
                                              n_rounds=2)),
     }
     for name, (sharded_fn, plain_fn) in runs.items():
-        clock.lap()
-        t0 = time.perf_counter()
         sharded = sharded_fn()
-        t_sharded, c_sharded = time.perf_counter() - t0, clock.lap()
         stats = [d.memory_stats() for d in devs[:4]]
         peaks = [st and st.get("peak_bytes_in_use") for st in stats]
         check(None in peaks or all(peaks),
               f"{name}: a mesh device held no data: {peaks}")
         with jax.default_device(devs[0]):
-            t0 = time.perf_counter()
             plain = plain_fn()
-            t_plain, c_plain = time.perf_counter() - t0, clock.lap()
         same = (json.dumps(sharded, sort_keys=True)
                 == json.dumps(plain, sort_keys=True))
         diff = None if same else _max_diff(sharded, plain)
@@ -283,10 +241,8 @@ def four_chips(clock: CompileClock) -> None:
                    f"largest difference {diff!r}")
         print(f"[smoke, not a benchmark] four-chips {name} on "
               f"{devs[0].platform} {devs[0].device_kind}: sharded over 4 vs "
-              f"unsharded on device 0: {verdict}; sharded compile_s="
-              f"{c_sharded} wall_s={t_sharded}; unsharded compile_s="
-              f"{c_plain} wall_s={t_plain}; device_peak_bytes={peaks}",
-              flush=True)
+              f"unsharded on device 0: {verdict}; "
+              f"device_peak_bytes={peaks}", flush=True)
         check(same, f"four-chips {name}: sharded != unsharded ({verdict})")
 
 
@@ -307,12 +263,11 @@ def main() -> None:
              f"this smoke never falls back to another backend")
     from repro.launch.cache import use_compile_cache
     print(f"compile cache: {use_compile_cache()}", flush=True)
-    clock = CompileClock()
     if args.four_chips:
-        four_chips(clock)
+        four_chips()
     else:
-        phase_a(dev, clock)
-        phase_b(dev, clock)
+        phase_a(dev)
+        phase_b(dev)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices())}}), flush=True)

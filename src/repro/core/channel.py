@@ -235,16 +235,17 @@ def make_problem(key: jax.Array, state: MobilityState, cfg: WirelessConfig,
     transmit-power deficit), ``payload_mbit`` [N] replaces the uniform
     Eq. (1) payload S in the bandwidth-time coefficients.
     """
-    k_snr, k_tc = jax.random.split(key)
-    snr = sample_snr(k_snr, state.distances(), cfg, shadow_db=shadow_db)
-    if power_scale is not None:
-        snr = snr * power_scale[:, None]
-    tcomp = sample_tcomp(k_tc, cfg, scale=tcomp_scale)
-    coeff = bandwidth_time_coeff(snr, cfg, payload_mbit=payload_mbit)
-    if bs_bw is None:
-        bs_bw = jnp.full((cfg.n_bs,), cfg.bs_bandwidth_mhz)
-    # works for both host ints and traced round counters (fused round scan)
-    necessary = part_counts < cfg.rho1 * (round_idx + 1)
+    with jax.named_scope("round.channel"):
+        k_snr, k_tc = jax.random.split(key)
+        snr = sample_snr(k_snr, state.distances(), cfg, shadow_db=shadow_db)
+        if power_scale is not None:
+            snr = snr * power_scale[:, None]
+        tcomp = sample_tcomp(k_tc, cfg, scale=tcomp_scale)
+        coeff = bandwidth_time_coeff(snr, cfg, payload_mbit=payload_mbit)
+        if bs_bw is None:
+            bs_bw = jnp.full((cfg.n_bs,), cfg.bs_bandwidth_mhz)
+        # works for both host ints and traced round counters (fused round scan)
+        necessary = part_counts < cfg.rho1 * (round_idx + 1)
     # host math: min_participants must stay a static int under tracing
     min_participants = int(math.ceil(cfg.rho2 * cfg.n_users))
     return SchedulingProblem(snr=snr, tcomp=tcomp, bs_bw=bs_bw, coeff=coeff,

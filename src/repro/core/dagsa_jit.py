@@ -77,6 +77,11 @@ def _bs_times_with_candidate(coeff, tcomp, assign, bs_bw, cand,
 def _schedule(snr, coeff, tcomp, bs_bw, necessary, min_participants, key,
               method="newton", iters=None, backend="jax", interpret=None,
               selection_block=None, snr_scale=None):
+    """The greedy: ``(assign, selected, user_bw, t_k, t_round,
+    greedy_steps)``, the last the int32 number of while-loop iterations,
+    one user added each (step 3 onwards).  Selection runs under
+    the ``dagsa.select`` scope and every Eq. (11) solve under
+    ``dagsa.eq11``, so a trace attributes their device time by name."""
     n, m = snr.shape
     solve = partial(_bs_times_with_candidate, method=method, iters=iters,
                     backend=backend, interpret=interpret)
@@ -124,14 +129,16 @@ def _schedule(snr, coeff, tcomp, bs_bw, necessary, min_participants, key,
             return _ref.masked_bs_argmax(s, rem, snr_scale)
 
     # -- step 1: necessary users to their best-channel BS ------------------
-    best_bs = _best_bs(snr)
+    with jax.named_scope("dagsa.select"):
+        best_bs = _best_bs(snr)
     assign0 = (jax.nn.one_hot(best_bs, m, dtype=bool)
                & necessary[:, None])
     remaining0 = ~necessary
 
-    t_bs0 = jax.vmap(
-        partial(bandwidth.bs_time, method=method, iters=iters),
-        in_axes=(1, None, 1, 0))(coeff, tcomp, assign0, bs_bw)
+    with jax.named_scope("dagsa.eq11"):
+        t_bs0 = jax.vmap(
+            partial(bandwidth.bs_time, method=method, iters=iters),
+            in_axes=(1, None, 1, 0))(coeff, tcomp, assign0, bs_bw)
     t_star0 = jnp.max(t_bs0)
 
     def n_selected(assign):
@@ -139,14 +146,17 @@ def _schedule(snr, coeff, tcomp, bs_bw, necessary, min_participants, key,
 
     def candidates(assign, remaining, t_bs):
         """Best-channel remaining user per BS + its trial t_k^*."""
-        cand, cand_val = _cands(snr, remaining)               # [M], [M]
-        t_with = solve(coeff, tcomp, assign, bs_bw, cand, t_bs=t_bs)
+        with jax.named_scope("dagsa.select"):
+            cand, cand_val = _cands(snr, remaining)           # [M], [M]
+        with jax.named_scope("dagsa.eq11"):
+            t_with = solve(coeff, tcomp, assign, bs_bw, cand, t_bs=t_bs)
         return cand, cand_val, t_with
 
     cand0, cval0, t_with0 = candidates(assign0, remaining0, t_bs0)
 
     def body(state):
-        assign, remaining, t_star, t_bs, cand, cand_val, t_with, key = state
+        (assign, remaining, t_star, t_bs, cand, cand_val, t_with, key,
+         steps) = state
         has_cand = jnp.any(remaining)
         feasible = (t_with <= t_star) & has_cand
         any_feasible = jnp.any(feasible)
@@ -183,28 +193,32 @@ def _schedule(snr, coeff, tcomp, bs_bw, necessary, min_participants, key,
         new_cand, new_cval, new_t_with = candidates(new_assign,
                                                     new_remaining, new_t_bs)
         return (new_assign, new_remaining, new_t_star, new_t_bs, new_cand,
-                new_cval, new_t_with, key)
+                new_cval, new_t_with, key, steps + 1)
 
     def cond(state):
-        assign, remaining, t_star, t_bs, cand, cand_val, t_with, key = state
+        assign, remaining, t_star, t_bs, cand, cand_val, t_with, key, _ = state
         any_feasible = jnp.any((t_with <= t_star) & jnp.any(remaining))
         need_more = n_selected(assign) < min_participants
         return jnp.any(remaining) & (any_feasible | need_more)
 
-    assign, *_ = jax.lax.while_loop(
+    # cond holds only while a user remains and a BS takes one, so every
+    # iteration adds exactly one user: ``steps`` counts them
+    assign, *_, steps = jax.lax.while_loop(
         cond, body,
-        (assign0, remaining0, t_star0, t_bs0, cand0, cval0, t_with0, key))
+        (assign0, remaining0, t_star0, t_bs0, cand0, cval0, t_with0, key,
+         jnp.int32(0)))
 
-    t_k, user_bw = bandwidth.solve_all(coeff, tcomp, assign, bs_bw,
-                                       method=method, iters=iters)
+    with jax.named_scope("dagsa.eq11"):
+        t_k, user_bw = bandwidth.solve_all(coeff, tcomp, assign, bs_bw,
+                                           method=method, iters=iters)
     selected = assign.any(axis=1)
-    return assign, selected, user_bw, t_k, jnp.max(t_k)
+    return assign, selected, user_bw, t_k, jnp.max(t_k), steps
 
 
 def dagsa_schedule_jit(problem: SchedulingProblem, key: jax.Array,
                        method: str = "newton", iters: int | None = None,
                        selection_block: int | None = None) -> ScheduleResult:
-    assign, selected, bw, t_k, t_round = _schedule(
+    assign, selected, bw, t_k, t_round, *_ = _schedule(
         problem.snr, problem.coeff, problem.tcomp, problem.bs_bw,
         problem.necessary, int(problem.min_participants), key,
         method=method, iters=iters, selection_block=selection_block)
@@ -283,7 +297,7 @@ def dagsa_schedule_batch(problems, keys: jax.Array, method: str = "newton",
     """
     if not isinstance(problems, SchedulingProblem):
         problems = stack_problems(problems)
-    assign, selected, bw, t_k, t_round = _schedule_batch(
+    assign, selected, bw, t_k, t_round, *_ = _schedule_batch(
         problems.snr, problems.coeff, problems.tcomp, problems.bs_bw,
         problems.necessary, int(problems.min_participants), keys,
         method=method, iters=iters, backend=backend, interpret=interpret,

@@ -203,8 +203,10 @@ def step_named(name: str, key: jax.Array, pos: jnp.ndarray, aux: dict,
         raise ValueError(f"unknown mobility model {name!r}; choose from "
                          f"{tuple(MOBILITY_MODELS)}")
     v = cfg.speed_mps if speed_mps is None else speed_mps
-    return MOBILITY_MODELS[name](key, pos, aux, cfg.area_m,
-                                 cfg.round_duration_s, v, pause_s, gm_memory)
+    with jax.named_scope("round.mobility"):
+        return MOBILITY_MODELS[name](key, pos, aux, cfg.area_m,
+                                     cfg.round_duration_s, v, pause_s,
+                                     gm_memory)
 
 
 def step_switch(model_id, key: jax.Array, pos: jnp.ndarray, aux: dict,
@@ -222,8 +224,9 @@ def step_switch(model_id, key: jax.Array, pos: jnp.ndarray, aux: dict,
          fn(k, p, a, area, dt, s, ps, gm))
         for fn in MOBILITY_MODELS.values()
     ]
-    return jax.lax.switch(model_id, branches, key, pos, aux, speed,
-                          pause_s, gm_memory)
+    with jax.named_scope("round.mobility"):
+        return jax.lax.switch(model_id, branches, key, pos, aux, speed,
+                              pause_s, gm_memory)
 
 
 # ------------------------------------------------------- legacy RD surface --

@@ -120,8 +120,9 @@ def fleet_local_sgd(loss_fn: Callable, global_params: PyTree,
     """
     fn = partial(local_sgd, loss_fn, epochs=epochs, batch_size=batch_size,
                  lr=lr)
-    return jax.vmap(lambda xx, yy, kk: fn(global_params, xx, yy, kk))(
-        x_all, y_all, keys)
+    with jax.named_scope("round.sgd"):
+        return jax.vmap(lambda xx, yy, kk: fn(global_params, xx, yy, kk))(
+            x_all, y_all, keys)
 
 
 def fleet_local_sgd_per_client(loss_fn: Callable, init_params: PyTree,
@@ -137,5 +138,6 @@ def fleet_local_sgd_per_client(loss_fn: Callable, init_params: PyTree,
     """
     fn = partial(local_sgd, loss_fn, epochs=epochs, batch_size=batch_size,
                  lr=lr)
-    return jax.vmap(lambda p, xx, yy, kk: fn(p, xx, yy, kk))(
-        init_params, x_all, y_all, keys)
+    with jax.named_scope("round.sgd"):
+        return jax.vmap(lambda p, xx, yy, kk: fn(p, xx, yy, kk))(
+            init_params, x_all, y_all, keys)

@@ -280,6 +280,10 @@ class RoundRecord:
     n_selected: int
     test_acc: float       # nan when not evaluated this round
     min_part_rate: float  # min_i counts_i / n — fairness monitor (Eq. 8g)
+    n_trained: int = -1   # clients whose local SGD the round ran (all N
+                          # under compute="full", the gather's cap under
+                          # "selected"); n_selected / n_trained is the
+                          # share of that SGD Eq. (2) kept
     handover_rate: float = float("nan")  # fraction of users whose serving
                                          # BS changed this round
                                          # (hierarchical runs only)
@@ -309,13 +313,15 @@ def _compress_updates(ref_params: PyTree, client_params: PyTree,
     per-client references ([N, d...], the hierarchical serving-edge init).
     """
     from repro.kernels import compress_topk as ct
-    delta = jax.tree.map(
-        lambda c, g: c - (g if g.ndim == c.ndim else g[None]).astype(c.dtype),
-        client_params, ref_params)
-    finite = fl_server.finite_update_mask(delta)
-    codes, scales = ct.compress_delta_tree(
-        delta, topk_frac, quantize=(compress == "topk-int8"), key=key,
-        backend="pallas" if fedavg_backend == "pallas" else "jax")
+    with jax.named_scope("round.compress"):
+        delta = jax.tree.map(
+            lambda c, g: c - (g if g.ndim == c.ndim
+                              else g[None]).astype(c.dtype),
+            client_params, ref_params)
+        finite = fl_server.finite_update_mask(delta)
+        codes, scales = ct.compress_delta_tree(
+            delta, topk_frac, quantize=(compress == "topk-int8"), key=key,
+            backend="pallas" if fedavg_backend == "pallas" else "jax")
     return codes, scales, finite
 
 
@@ -372,24 +378,26 @@ def train_and_aggregate(loss_fn, params: PyTree, x_clients, y_clients, keys,
     if corr is not None:
         client_params = fl_faults.corrupt_updates(
             client_params, corr, corrupt_mode_id, corrupt_scale)
-    if compress is not None:
-        codes, scales, finite = _compress_updates(
-            params, client_params, compress, topk_frac, compress_key,
-            fedavg_backend)
-        sel = sel & finite
-        if fedavg_backend == "pallas":
-            from repro.kernels.compress_topk import fedavg_decompress_reduce
+    with jax.named_scope("round.aggregate"):
+        if compress is not None:
+            codes, scales, finite = _compress_updates(
+                params, client_params, compress, topk_frac, compress_key,
+                fedavg_backend)
+            sel = sel & finite
+            if fedavg_backend == "pallas":
+                from repro.kernels.compress_topk import \
+                    fedavg_decompress_reduce
+                return fedavg_decompress_reduce(params, codes, scales, sel,
+                                                sizes, clip_norm=clip_norm)
+            from repro.kernels.ref import fedavg_decompress_reduce
             return fedavg_decompress_reduce(params, codes, scales, sel,
                                             sizes, clip_norm=clip_norm)
-        from repro.kernels.ref import fedavg_decompress_reduce
-        return fedavg_decompress_reduce(params, codes, scales, sel, sizes,
-                                        clip_norm=clip_norm)
-    if fedavg_backend == "pallas":
-        from repro.kernels.fedavg_reduce import fedavg_reduce
-        return fedavg_reduce(params, client_params, sel, sizes,
-                             clip_norm=clip_norm)
-    return fl_server.fedavg(params, client_params, sel, sizes,
-                            clip_norm=clip_norm)
+        if fedavg_backend == "pallas":
+            from repro.kernels.fedavg_reduce import fedavg_reduce
+            return fedavg_reduce(params, client_params, sel, sizes,
+                                 clip_norm=clip_norm)
+        return fl_server.fedavg(params, client_params, sel, sizes,
+                                clip_norm=clip_norm)
 
 
 # ---------------------------------------------------- buffered-async engine --
@@ -581,26 +589,29 @@ def async_round_tick(loss_fn, params: PyTree, queue: tuple, x_clients,
             params, client_params, compress, topk_frac, compress_key,
             fedavg_backend)
         from repro.kernels.compress_topk import decompress_tree
-        client_params = jax.tree.map(
-            lambda g, d: g[None] + d.astype(g.dtype), params,
-            decompress_tree(codes, scales))
-        if admit_idx is None:
-            dispatch = dispatch & finite
-        else:
-            # scatter the [cap] finite rows back to the [N] dispatch mask
-            # (padding duplicates carry identical rows, so last-write-wins
-            # scatters the same value)
-            dispatch = dispatch & jnp.ones_like(dispatch).at[admit_idx].set(
-                finite, mode="drop")
+        with jax.named_scope("round.compress"):
+            client_params = jax.tree.map(
+                lambda g, d: g[None] + d.astype(g.dtype), params,
+                decompress_tree(codes, scales))
+            if admit_idx is None:
+                dispatch = dispatch & finite
+            else:
+                # scatter the [cap] finite rows back to the [N] dispatch
+                # mask (padding duplicates carry identical rows, so
+                # last-write-wins scatters the same value)
+                dispatch = dispatch & jnp.ones_like(dispatch).at[
+                    admit_idx].set(finite, mode="drop")
     now = jnp.asarray(r, jnp.float32) * jnp.float32(tick_s)
     comp_time = now + t_user
     tick_end = now + jnp.float32(tick_s)
-    queue, delivered, wstale, delivered_upd, diag = async_queue_step(
-        queue, client_params, dispatch, comp_time, data_sizes, r, tick_end,
-        staleness_alpha, admit_idx=admit_idx)
-    params = aggregate_weighted(params, delivered_upd, delivered, data_sizes,
-                                wstale, fedavg_backend=fedavg_backend,
-                                clip_norm=clip_norm)
+    with jax.named_scope("round.aggregate"):
+        queue, delivered, wstale, delivered_upd, diag = async_queue_step(
+            queue, client_params, dispatch, comp_time, data_sizes, r,
+            tick_end, staleness_alpha, admit_idx=admit_idx)
+        params = aggregate_weighted(params, delivered_upd, delivered,
+                                    data_sizes, wstale,
+                                    fedavg_backend=fedavg_backend,
+                                    clip_norm=clip_norm)
     return params, queue, delivered, diag
 
 
@@ -680,49 +691,52 @@ def hierarchical_round(loss_fn, global_params: PyTree, edge_params: PyTree,
         client_params = fl_faults.corrupt_updates(
             client_params, corr, corrupt_mode_id, corrupt_scale)
 
-    # edge Eq. (2): every BS aggregates its users in one segment-reduce
-    if compress is not None:
-        # compressed uplink: deltas vs the SERVING edge model (what the
-        # client trained from), decoded into the ASSIGNED BS's aggregation
-        # — the [N, model] client tensor never reconstructs densely on the
-        # pallas backend (docs/COMPRESSION.md)
-        codes, scales, finite = _compress_updates(
-            init, client_params, compress, topk_frac, compress_key,
-            fedavg_backend)
-        assign_r = assign_r & finite[:, None]
-        if fedavg_backend == "pallas":
-            from repro.kernels.compress_topk import \
-                fedavg_decompress_segment_reduce
-            edge_params = fedavg_decompress_segment_reduce(
-                edge_params, codes, scales, assign_r, serving_r, sizes,
-                clip_norm=clip_norm)
+    with jax.named_scope("round.aggregate"):
+        # edge Eq. (2): every BS aggregates its users in one segment-reduce
+        if compress is not None:
+            # compressed uplink: deltas vs the SERVING edge model (what
+            # the client trained from), decoded into the ASSIGNED BS's
+            # aggregation — the [N, model] client tensor never
+            # reconstructs densely on the pallas backend
+            # (docs/COMPRESSION.md)
+            codes, scales, finite = _compress_updates(
+                init, client_params, compress, topk_frac, compress_key,
+                fedavg_backend)
+            assign_r = assign_r & finite[:, None]
+            if fedavg_backend == "pallas":
+                from repro.kernels.compress_topk import \
+                    fedavg_decompress_segment_reduce
+                edge_params = fedavg_decompress_segment_reduce(
+                    edge_params, codes, scales, assign_r, serving_r, sizes,
+                    clip_norm=clip_norm)
+            else:
+                from repro.kernels.ref import \
+                    fedavg_decompress_segment_reduce
+                edge_params = fedavg_decompress_segment_reduce(
+                    edge_params, codes, scales, assign_r, serving_r, sizes,
+                    clip_norm=clip_norm)
+        elif fedavg_backend == "pallas":
+            from repro.kernels.fedavg_reduce import fedavg_segment_reduce
+            edge_params = fedavg_segment_reduce(edge_params, client_params,
+                                                assign_r, sizes,
+                                                clip_norm=clip_norm)
         else:
-            from repro.kernels.ref import fedavg_decompress_segment_reduce
-            edge_params = fedavg_decompress_segment_reduce(
-                edge_params, codes, scales, assign_r, serving_r, sizes,
+            edge_params = fl_server.fedavg_segmented(
+                edge_params, client_params, assign_r, sizes,
                 clip_norm=clip_norm)
-    elif fedavg_backend == "pallas":
-        from repro.kernels.fedavg_reduce import fedavg_segment_reduce
-        edge_params = fedavg_segment_reduce(edge_params, client_params,
-                                            assign_r, sizes,
-                                            clip_norm=clip_norm)
-    else:
-        edge_params = fl_server.fedavg_segmented(edge_params, client_params,
-                                                 assign_r, sizes,
-                                                 clip_norm=clip_norm)
-    _, bs_totals = fl_server.segment_weights(assign_r, sizes)
-    edge_weight = edge_weight + bs_totals
+        _, bs_totals = fl_server.segment_weights(assign_r, sizes)
+        edge_weight = edge_weight + bs_totals
 
-    def sync(args):
-        g, e, wgt = args
-        g2 = fl_server.edge_global_sync(g, e, wgt)
-        e2 = jax.tree.map(
-            lambda gl, el: jnp.broadcast_to(gl[None], el.shape), g2, e)
-        return g2, e2, jnp.zeros_like(wgt)
+        def sync(args):
+            g, e, wgt = args
+            g2 = fl_server.edge_global_sync(g, e, wgt)
+            e2 = jax.tree.map(
+                lambda gl, el: jnp.broadcast_to(gl[None], el.shape), g2, e)
+            return g2, e2, jnp.zeros_like(wgt)
 
-    global_params, edge_params, edge_weight = jax.lax.cond(
-        (r + 1) % tau_global == 0, sync, lambda a: a,
-        (global_params, edge_params, edge_weight))
+        global_params, edge_params, edge_weight = jax.lax.cond(
+            (r + 1) % tau_global == 0, sync, lambda a: a,
+            (global_params, edge_params, edge_weight))
     return global_params, edge_params, edge_weight, serving, handover_rate
 
 
@@ -902,9 +916,10 @@ def make_round_step(plan: RoundPlan, w: WirelessConfig, *, scenario, faults,
             mstate = MobilityState(user_pos=pos, bs_pos=bs_pos)
             shadow_db = None
             if scenario["shadow_sigma"] > 0.0:
-                shadow_db = scenario["shadow_sigma"] * \
-                    channel.sample_shadowing(k_shadow, pos, bs_pos, w,
-                                             sigma_db=1.0)
+                with jax.named_scope("round.channel"):
+                    shadow_db = scenario["shadow_sigma"] * \
+                        channel.sample_shadowing(k_shadow, pos, bs_pos, w,
+                                                 sigma_db=1.0)
             prob = channel.make_problem(k_prob, mstate, w, counts, r,
                                         bs_bw=bs_bw, shadow_db=shadow_db,
                                         tcomp_scale=het_tcomp,
@@ -926,36 +941,39 @@ def make_round_step(plan: RoundPlan, w: WirelessConfig, *, scenario, faults,
             pos, aux = mobility.step_switch(
                 p["model_id"], k_mob, pos, aux, w.area_m,
                 w.round_duration_s, p["speed"], p["pause_s"], p["gm_memory"])
-            # same k_shadow every round -> the field is consistent in time
-            dist, shadow_db = channel.dist_and_shadow(
-                pos, bs_pos, p["shadow_sigma"], k_shadow, w, plan.user_chunk)
-            # device PSD spread scales SNR BEFORE encoding, so int8/bf16
-            # channel codes carry the heterogeneous link (exact no-op at
-            # the 0 dB default: het_power == 1.0 elementwise)
-            snr_raw = channel.sample_snr(k_snr, dist, w,
-                                         shadow_db=shadow_db) \
-                * het_power[:, None]
-            snr_store, snr_scale, snr_lin = channel.encode_channel(
-                snr_raw, plan.channel_dtype)
-            if plan.channel_dtype == "int8":
-                # Eq. (11) needs real coefficients — derive from the
-                # dequantised plane (f32; the codes carry only ranks+dB)
-                coeff = channel.bandwidth_time_coeff(
-                    snr_lin, w, payload_mbit=payload0)
-            else:
-                coeff = channel.compress_channel(
-                    channel.bandwidth_time_coeff(snr_store, w,
-                                                 payload_mbit=payload0),
-                    plan.channel_dtype)
-            u = jax.random.uniform(k_tc, (n,))
-            tcomp = (p["tcomp_min"]
-                     + u * (p["tcomp_max"] - p["tcomp_min"])) * het_tcomp
-            # Eq. (8g), post-round requirement (matches make_problem)
-            necessary = counts < w.rho1 * (r + 1.0)
-            prob = SchedulingProblem(snr=snr_lin, tcomp=tcomp, bs_bw=bs_bw,
-                                     coeff=coeff, necessary=necessary,
-                                     min_participants=min_participants,
-                                     payload_mbit=payload0)
+            with jax.named_scope("round.channel"):
+                # same k_shadow every round -> the field is consistent in
+                # time
+                dist, shadow_db = channel.dist_and_shadow(
+                    pos, bs_pos, p["shadow_sigma"], k_shadow, w,
+                    plan.user_chunk)
+                # device PSD spread scales SNR BEFORE encoding, so int8/bf16
+                # channel codes carry the heterogeneous link (exact no-op
+                # at the 0 dB default: het_power == 1.0 elementwise)
+                snr_raw = channel.sample_snr(k_snr, dist, w,
+                                             shadow_db=shadow_db) \
+                    * het_power[:, None]
+                snr_store, snr_scale, snr_lin = channel.encode_channel(
+                    snr_raw, plan.channel_dtype)
+                if plan.channel_dtype == "int8":
+                    # Eq. (11) needs real coefficients — derive from the
+                    # dequantised plane (f32; the codes carry only ranks+dB)
+                    coeff = channel.bandwidth_time_coeff(
+                        snr_lin, w, payload_mbit=payload0)
+                else:
+                    coeff = channel.compress_channel(
+                        channel.bandwidth_time_coeff(snr_store, w,
+                                                     payload_mbit=payload0),
+                        plan.channel_dtype)
+                u = jax.random.uniform(k_tc, (n,))
+                tcomp = (p["tcomp_min"] + u * (p["tcomp_max"]
+                                               - p["tcomp_min"])) * het_tcomp
+                # Eq. (8g), post-round requirement (matches make_problem)
+                necessary = counts < w.rho1 * (r + 1.0)
+                prob = SchedulingProblem(
+                    snr=snr_lin, tcomp=tcomp, bs_bw=bs_bw, coeff=coeff,
+                    necessary=necessary, min_participants=min_participants,
+                    payload_mbit=payload0)
         else:
             raise ValueError(f"unknown world {plan.world!r}; "
                              f"choose 'engine' or 'sweep'")
@@ -972,29 +990,32 @@ def make_round_step(plan: RoundPlan, w: WirelessConfig, *, scenario, faults,
                 prob = dataclasses.replace(prob, p_deliver=p_est)
 
         # -- 3. schedule (static dispatch by name) -------------------------
-        sched_state = state.sched
-        if plan.scheduler in sched.STATEFUL_SCHEDULERS:
-            res, sched_state = sched.schedule_stateful(
-                plan.scheduler, prob, w, k_sched, sched_state)
-        elif plan.world == "engine":
-            res = sched.schedule(plan.scheduler, prob, w, k_sched)
-        elif plan.scheduler in ("dagsa_jit", "dagsa-r"):
-            # direct greedy call: the sweep streams the (possibly int8/bf16)
-            # channel codes + scale through the selection kernels
-            score, scale = snr_store, snr_scale
-            if plan.faults_on and plan.scheduler == "dagsa-r":
-                # the delivery-discounted candidate score (the per-user
-                # scale leaves each user's best-BS argmax unchanged)
-                score = prob.snr * jnp.clip(p_est, 0.0, 1.0)[:, None]
-                scale = None
-            assign, selected, user_bw, t_k, t_star = dagsa_jit._schedule(
-                score, prob.coeff, prob.tcomp, bs_bw, prob.necessary,
-                min_participants, k_sched, backend=plan.backend,
-                selection_block=plan.user_chunk, snr_scale=scale)
-            res = ScheduleResult(assign=assign, selected=selected,
-                                 bw=user_bw, bs_time=t_k, t_round=t_star)
-        else:
-            res = sched.schedule(plan.scheduler, prob, w, k_sched)
+        with jax.named_scope("round.select"):
+            sched_state = state.sched
+            if plan.scheduler in sched.STATEFUL_SCHEDULERS:
+                res, sched_state = sched.schedule_stateful(
+                    plan.scheduler, prob, w, k_sched, sched_state)
+            elif plan.world == "engine":
+                res = sched.schedule(plan.scheduler, prob, w, k_sched)
+            elif plan.scheduler in ("dagsa_jit", "dagsa-r"):
+                # direct greedy call: the sweep streams the (possibly
+                # int8/bf16) channel codes + scale through the selection
+                # kernels
+                score, scale = snr_store, snr_scale
+                if plan.faults_on and plan.scheduler == "dagsa-r":
+                    # the delivery-discounted candidate score (the per-user
+                    # scale leaves each user's best-BS argmax unchanged)
+                    score = prob.snr * jnp.clip(p_est, 0.0, 1.0)[:, None]
+                    scale = None
+                assign, selected, user_bw, t_k, t_star, *_ = \
+                    dagsa_jit._schedule(
+                        score, prob.coeff, prob.tcomp, bs_bw, prob.necessary,
+                        min_participants, k_sched, backend=plan.backend,
+                        selection_block=plan.user_chunk, snr_scale=scale)
+                res = ScheduleResult(assign=assign, selected=selected,
+                                     bw=user_bw, bs_time=t_k, t_round=t_star)
+            else:
+                res = sched.schedule(plan.scheduler, prob, w, k_sched)
 
         # -- 3b. realize faults: stragglers stretch tcomp, outages/crashes
         # kill uplinks, the deadline drops late survivors (truncated Eq. 3)
@@ -1087,10 +1108,14 @@ def make_round_step(plan: RoundPlan, w: WirelessConfig, *, scenario, faults,
         # faults: a user whose update was lost stays "necessary" (Eq. 8g),
         # so the fairness loop self-heals failures.
         counts = counts + delivered.astype(counts.dtype)
+
+        def evaluate(a):
+            with jax.named_scope("round.eval"):
+                return cnn.accuracy(eval_model(a), x_test, y_test)
+
         if plan.eval_every:
             acc = jax.lax.cond(
-                (r + 1) % plan.eval_every == 0,
-                lambda a: cnn.accuracy(eval_model(a), x_test, y_test),
+                (r + 1) % plan.eval_every == 0, evaluate,
                 lambda a: jnp.float32(jnp.nan), eval_args)
         else:
             acc = jnp.float32(jnp.nan)
@@ -1104,6 +1129,11 @@ def make_round_step(plan: RoundPlan, w: WirelessConfig, *, scenario, faults,
         if plan.world == "engine":
             # engine records keep integer dtypes (host RoundRecords)
             out["n_selected"] = n_sel.astype(jnp.int32)
+            # clients whose local SGD the round ran: the whole fleet, or
+            # the static gather's cap
+            out["n_trained"] = jnp.int32(
+                n if plan.compute == "full"
+                else fl_client.resolve_cap(n, plan.select_cap))
             if plan.async_on:
                 n_del = diag["n_delivered"]
                 out["n_delivered"] = n_del
@@ -1510,14 +1540,6 @@ class FLSimulation:
             raise ValueError(
                 f"aggregation_async=True runs mode='async' only (the event "
                 f"queue rides the scan carry); got mode={mode!r}")
-        if mode == "async":
-            if n_rounds <= 0:
-                return []
-            carry, outs = self._async_scan_jit(self._carry(), self.round_idx,
-                                               n_rounds=n_rounds)
-            self.round_idx += n_rounds
-            self._set_carry(carry)
-            return self._finish(outs, n_rounds)
         if mode in ("fused", "step") and not self.fused_capable:
             raise ValueError(
                 f"scheduler {self.cfg.scheduler!r} does not trace; "
@@ -1538,29 +1560,45 @@ class FLSimulation:
                 f"restart them every round — use mode='fused' or 'step'")
         if n_rounds <= 0:
             return []
+        # host spans on the profiler's clock: the whole call, building the
+        # carry and enqueueing the rounds, then bringing records back
+        # (``fl.fetch`` in :meth:`_finish`); each names its first round
+        r0 = self.round_idx
+        with jax.profiler.TraceAnnotation("fl.call", round=r0):
+            if mode == "eager":
+                return [self._run_round_eager() for _ in range(n_rounds)]
+            with jax.profiler.TraceAnnotation("fl.dispatch", round=r0,
+                                              rounds=n_rounds):
+                carry, outs = self._dispatch(mode, n_rounds)
+            self.round_idx += n_rounds
+            self._set_carry(carry)
+            return self._finish(outs, n_rounds)
+
+    def _dispatch(self, mode: str, n_rounds: int) -> tuple[RoundState, dict]:
+        """Enqueue ``n_rounds`` compiled rounds from the current carry;
+        returns the new carry and the stacked device records."""
+        if mode == "async":
+            return self._async_scan_jit(self._carry(), self.round_idx,
+                                        n_rounds=n_rounds)
         if mode == "fused":
-            carry, outs = self._scan_jit(self._carry(), self.round_idx,
-                                         n_rounds=n_rounds)
-        elif mode == "step":
+            return self._scan_jit(self._carry(), self.round_idx,
+                                  n_rounds=n_rounds)
+        if mode == "step":
             carry, collected = self._carry(), []
             for r in range(self.round_idx, self.round_idx + n_rounds):
                 carry, out = self._step_jit(carry, r)
                 collected.append(out)
-            outs = {k: jnp.stack([o[k] for o in collected])
-                    for k in collected[0]}
-        elif mode == "eager":
-            return [self._run_round_eager() for _ in range(n_rounds)]
-        else:
-            raise ValueError(f"unknown mode {mode!r}")
-        self.round_idx += n_rounds
-        self._set_carry(carry)
-        return self._finish(outs, n_rounds)
+            return carry, {k: jnp.stack([o[k] for o in collected])
+                           for k in collected[0]}
+        raise ValueError(f"unknown mode {mode!r}")
 
     def _finish(self, outs: dict, n_rounds: int) -> list[RoundRecord]:
         """Stacked device records -> host RoundRecords (ONE transfer)."""
-        outs = jax.tree.map(np.asarray, outs)        # the only host sync
+        r0 = self.round_idx - n_rounds         # round_idx already advanced
+        with jax.profiler.TraceAnnotation("fl.fetch", round=r0):
+            outs = jax.tree.map(np.asarray, outs)    # the only host sync
+        first = r0 + 1
         wall = self.wall_clock + np.cumsum(outs["t_round"], dtype=np.float64)
-        first = self.round_idx - n_rounds + 1  # round_idx already advanced
         hand = outs.get("handover_rate")
         n_del = outs.get("n_delivered")
         n_inf = outs.get("n_inflight")
@@ -1571,6 +1609,7 @@ class FLSimulation:
                             n_selected=int(outs["n_selected"][i]),
                             test_acc=float(outs["test_acc"][i]),
                             min_part_rate=float(outs["min_part_rate"][i]),
+                            n_trained=int(outs["n_trained"][i]),
                             handover_rate=(float(hand[i]) if hand is not None
                                            else float("nan")),
                             n_delivered=(int(n_del[i]) if n_del is not None
@@ -1675,7 +1714,8 @@ class FLSimulation:
         rec = RoundRecord(round_idx=self.round_idx, t_round=t_round,
                           wall_clock=self.wall_clock,
                           n_selected=int(res.selected.sum()),
-                          test_acc=acc, min_part_rate=min_rate)
+                          test_acc=acc, min_part_rate=min_rate,
+                          n_trained=w.n_users)
         if self._faulty:
             n_sel = max(int(res.selected.sum()), 1)
             n_del = int(delivered.sum())

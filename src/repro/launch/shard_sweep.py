@@ -377,6 +377,6 @@ def shard_schedule_batch(problems, keys: jax.Array, method: str = "newton",
                           method=method, iters=iters, backend=backend,
                           interpret=interpret,
                           selection_block=selection_block)
-    assign, selected, bw, t_k, t_round = unpad_leading(out, fleet)
+    assign, selected, bw, t_k, t_round, *_ = unpad_leading(out, fleet)
     return ScheduleResult(assign=assign, selected=selected, bw=bw,
                           bs_time=t_k, t_round=t_round)

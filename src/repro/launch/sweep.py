@@ -41,7 +41,8 @@ Wireless record schema (one dict per scenario, JSON list on stdout /
      "min_part_rate": float,           # final-round min_i counts_i / round,
                                        #   the Eq. (8g) fairness monitor
      "curves": {"t_round_s": [R], "n_selected": [R],
-                "min_part_rate": [R]}} # per-round means across seeds
+                "min_part_rate": [R],  # per-round means across seeds
+                "greedy_steps": [R]}}  # DAGSA while-loop iterations
 
 Learning records add (see :func:`run_learning_sweep`):
 
@@ -175,15 +176,21 @@ def _one_cell(p: dict, key: jax.Array, cfg: WirelessConfig, n_rounds: int,
     solves upcast per block/row.  ``user_chunk`` additionally routes
     Algorithm 1 steps 1/3 through the streaming chunked selection
     (bit-identical decisions, no [N, M] selection temporaries).
+
+    This body carries the round step's span names by copy
+    (:func:`repro.fl.rounds.make_round_step`), plus ``world.init``, and
+    returns the greedy's iteration count as ``greedy_steps``.
     """
-    k_pos, k_bs, k_bw, k_aux, k_shadow, k_run = jax.random.split(key, 6)
-    pos0 = jax.random.uniform(k_pos, (cfg.n_users, 2), minval=0.0,
-                              maxval=cfg.area_m)
-    bs_pos = _bs_positions(k_bs, p["layout_id"], cfg)
-    bs_bw = p["bw_min"] + jax.random.uniform(k_bw, (cfg.n_bs,)) * \
-        (p["bw_max"] - p["bw_min"])
-    aux0 = mobility.init_aux(k_aux, cfg.n_users, cfg, speed_mps=p["speed"])
-    counts0 = jnp.zeros((cfg.n_users,))
+    with jax.named_scope("world.init"):
+        k_pos, k_bs, k_bw, k_aux, k_shadow, k_run = jax.random.split(key, 6)
+        pos0 = jax.random.uniform(k_pos, (cfg.n_users, 2), minval=0.0,
+                                  maxval=cfg.area_m)
+        bs_pos = _bs_positions(k_bs, p["layout_id"], cfg)
+        bs_bw = p["bw_min"] + jax.random.uniform(k_bw, (cfg.n_bs,)) * \
+            (p["bw_max"] - p["bw_min"])
+        aux0 = mobility.init_aux(k_aux, cfg.n_users, cfg,
+                                 speed_mps=p["speed"])
+        counts0 = jnp.zeros((cfg.n_users,))
 
     def round_body(carry, r):
         pos, aux, counts, key = carry
@@ -191,35 +198,42 @@ def _one_cell(p: dict, key: jax.Array, cfg: WirelessConfig, n_rounds: int,
         pos, aux = mobility.step_switch(
             p["model_id"], k_mob, pos, aux, cfg.area_m, cfg.round_duration_s,
             p["speed"], p["pause_s"], p["gm_memory"])
-        # same k_shadow every round -> the field is consistent over time;
-        # sigma 0 (scenario off) makes it a no-op multiplier.
-        dist, shadow_db = _dist_and_shadow(pos, bs_pos, p["shadow_sigma"],
-                                           k_shadow, cfg, user_chunk)
-        snr_store, snr_scale, snr_lin = channel.encode_channel(
-            channel.sample_snr(k_snr, dist, cfg, shadow_db=shadow_db),
-            channel_dtype)
-        if channel_dtype == "int8":
-            # Eq. (11) needs real coefficients — derive from the dequantised
-            # plane (the int8 codes carry only ranks + dB values)
-            coeff = channel.bandwidth_time_coeff(snr_lin, cfg)
-        else:
-            coeff = channel.compress_channel(
-                channel.bandwidth_time_coeff(snr_store, cfg), channel_dtype)
-        u = jax.random.uniform(k_tc, (cfg.n_users,))
-        tcomp = p["tcomp_min"] + u * (p["tcomp_max"] - p["tcomp_min"])
-        # Eq. (8g): post-round requirement — participate if sitting out
-        # would leave the count below rho1 * (rounds so far INCLUDING this
-        # one); matches channel.make_problem.
-        necessary = counts < cfg.rho1 * (r + 1.0)
-        _, selected, _, _, t_round = dagsa_jit._schedule(
-            snr_store, coeff, tcomp, bs_bw, necessary, min_participants,
-            k_sched, backend=backend, selection_block=user_chunk,
-            snr_scale=snr_scale)
+        with jax.named_scope("round.channel"):
+            # same k_shadow every round -> the field is consistent over
+            # time; sigma 0 (scenario off) makes it a no-op multiplier.
+            dist, shadow_db = _dist_and_shadow(pos, bs_pos,
+                                               p["shadow_sigma"], k_shadow,
+                                               cfg, user_chunk)
+            snr_store, snr_scale, snr_lin = channel.encode_channel(
+                channel.sample_snr(k_snr, dist, cfg, shadow_db=shadow_db),
+                channel_dtype)
+            if channel_dtype == "int8":
+                # Eq. (11) needs real coefficients — derive from the
+                # dequantised plane (the int8 codes carry only ranks + dB
+                # values)
+                coeff = channel.bandwidth_time_coeff(snr_lin, cfg)
+            else:
+                coeff = channel.compress_channel(
+                    channel.bandwidth_time_coeff(snr_store, cfg),
+                    channel_dtype)
+            u = jax.random.uniform(k_tc, (cfg.n_users,))
+            tcomp = p["tcomp_min"] + u * (p["tcomp_max"] - p["tcomp_min"])
+            # Eq. (8g): post-round requirement — participate if sitting out
+            # would leave the count below rho1 * (rounds so far INCLUDING
+            # this one); matches channel.make_problem.
+            necessary = counts < cfg.rho1 * (r + 1.0)
+        with jax.named_scope("round.select"):
+            decision = dagsa_jit._schedule(
+                snr_store, coeff, tcomp, bs_bw, necessary, min_participants,
+                k_sched, backend=backend, selection_block=user_chunk,
+                snr_scale=snr_scale)
+        selected, t_round = decision[1], decision[4]
         counts = counts + selected.astype(counts.dtype)
         out = {
             "t_round": t_round,
             "n_selected": jnp.sum(selected).astype(jnp.float32),
             "min_part_rate": jnp.min(counts) / (r + 1.0),
+            "greedy_steps": decision[-1],
         }
         return (pos, aux, counts, key), out
 
@@ -275,6 +289,7 @@ def _wireless_records(group: list[tuple[int, ScenarioSpec]], outs: dict,
     t_round = np.asarray(outs["t_round"])            # [S, seeds, R]
     n_sel = np.asarray(outs["n_selected"])
     min_pr = np.asarray(outs["min_part_rate"])
+    steps = np.asarray(outs["greedy_steps"])
     records: dict[int, dict] = {}
     for i, (pos, spec) in enumerate(group):
         records[pos] = {
@@ -291,6 +306,7 @@ def _wireless_records(group: list[tuple[int, ScenarioSpec]], outs: dict,
                 "t_round_s": t_round[i].mean(axis=0).tolist(),
                 "n_selected": n_sel[i].mean(axis=0).tolist(),
                 "min_part_rate": min_pr[i].mean(axis=0).tolist(),
+                "greedy_steps": steps[i].mean(axis=0).tolist(),
             },
         }
     return records
@@ -314,18 +330,27 @@ def run_sweep(scenarios: Sequence[str | ScenarioSpec], n_seeds: int = 4,
     specs = [get_scenario(s) if isinstance(s, str) else s for s in scenarios]
     base = cfg or WirelessConfig()
     records: dict[int, dict] = {}       # original position -> record
-    for (n_users, n_bs), group in _wireless_buckets(specs, base).items():
-        _check_user_chunk(user_chunk, n_users)
-        bcfg = dataclasses.replace(base, n_bs=n_bs)
-        minp = int(np.ceil(bcfg.rho2 * n_users))
-        params = _scenario_params([s for _, s in group], bcfg)
-        outs = _sweep_bucket(params, jax.random.PRNGKey(seed), cfg=bcfg,
-                             n_rounds=n_rounds, n_seeds=n_seeds,
-                             min_participants=minp, backend=backend,
-                             user_chunk=user_chunk,
-                             channel_dtype=channel_dtype,
-                             n_models=len(mobility.MOBILITY_MODELS))
-        records.update(_wireless_records(group, outs, n_seeds, n_rounds))
+    # host spans on the profiler's clock, each naming the call's seed:
+    # the whole call, the scenario arrays, the bucket's enqueue, and the
+    # records brought back to the host
+    span = jax.profiler.TraceAnnotation
+    with span("sweep.call", seed=seed):
+        for (n_users, n_bs), group in _wireless_buckets(specs, base).items():
+            _check_user_chunk(user_chunk, n_users)
+            bcfg = dataclasses.replace(base, n_bs=n_bs)
+            minp = int(np.ceil(bcfg.rho2 * n_users))
+            with span("sweep.params", seed=seed):
+                params = _scenario_params([s for _, s in group], bcfg)
+            with span("sweep.dispatch", seed=seed):
+                outs = _sweep_bucket(params, jax.random.PRNGKey(seed),
+                                     cfg=bcfg, n_rounds=n_rounds,
+                                     n_seeds=n_seeds, min_participants=minp,
+                                     backend=backend, user_chunk=user_chunk,
+                                     channel_dtype=channel_dtype,
+                                     n_models=len(mobility.MOBILITY_MODELS))
+            with span("sweep.fetch", seed=seed):
+                records.update(_wireless_records(group, outs, n_seeds,
+                                                 n_rounds))
     # preserve the caller's scenario order
     return [records[i] for i in range(len(specs))]
 
@@ -378,14 +403,16 @@ def _one_learning_cell(p: dict, key: jax.Array, x_c, y_c, params0,
     from repro.fl.rounds import RoundPlan, make_round_step
 
     fp = {k: p[f"f_{k}"] for k in fl_faults.FAULT_PARAM_KEYS}
-    k_pos, k_bs, k_bw, k_aux, k_shadow, k_run = jax.random.split(key, 6)
-    pos0 = jax.random.uniform(k_pos, (cfg.n_users, 2), minval=0.0,
-                              maxval=cfg.area_m)
-    bs_pos = _bs_positions(k_bs, p["layout_id"], cfg)
-    bs_bw = p["bw_min"] + jax.random.uniform(k_bw, (cfg.n_bs,)) * \
-        (p["bw_max"] - p["bw_min"])
-    aux0 = mobility.init_aux(k_aux, cfg.n_users, cfg, speed_mps=p["speed"])
-    counts0 = jnp.zeros((cfg.n_users,))
+    with jax.named_scope("world.init"):
+        k_pos, k_bs, k_bw, k_aux, k_shadow, k_run = jax.random.split(key, 6)
+        pos0 = jax.random.uniform(k_pos, (cfg.n_users, 2), minval=0.0,
+                                  maxval=cfg.area_m)
+        bs_pos = _bs_positions(k_bs, p["layout_id"], cfg)
+        bs_bw = p["bw_min"] + jax.random.uniform(k_bw, (cfg.n_bs,)) * \
+            (p["bw_max"] - p["bw_min"])
+        aux0 = mobility.init_aux(k_aux, cfg.n_users, cfg,
+                                 speed_mps=p["speed"])
+        counts0 = jnp.zeros((cfg.n_users,))
     data_sizes = jnp.full((cfg.n_users,), x_c.shape[1])
 
     plan = RoundPlan(

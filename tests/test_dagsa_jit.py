@@ -127,6 +127,6 @@ def test_jit_dagsa_vmappable():
                                          probs[0].min_participants, a[-1]),
                     in_axes=(0, 0, 0, 0, 0, 0))(
         snr, coeff, tcomp, bs_bw, nec, keys)
-    t_rounds = outs[-1]
+    t_rounds = outs[4]
     assert t_rounds.shape == (4,)
     assert np.isfinite(np.asarray(t_rounds)).all()
