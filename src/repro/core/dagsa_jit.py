@@ -24,14 +24,27 @@ Performance notes (the control-plane hot path):
   iteration rather than wall-clock — it is what makes a reduced ``iters``
   knob safe, and it lets the host-numpy mirror (which does early-exit)
   stop after a couple of Newton steps.
+* Each step's trial solves read each BS's compact member list, not an
+  [M, N] mask over every user: the loop state carries ``[M, W]`` f32
+  planes of the members' coefficients and computation times (zero past
+  each row's count), and BS k's trial is its row plus one lane holding
+  its candidate, ``[M, W + 1]`` with ``W + 1 = 128`` (one lane tile).
+  Masked-out users add exact zeros to Eq. (11), so only the order of the
+  f32 additions changes.  The compact path exists where ``N > W``; at
+  ``N <= W`` the greedy is the dense loop alone.
+* Hand-over: the compact loop stops when a BS holds ``W`` members, and a
+  second ``while_loop`` continues from the same state with the dense
+  [M, N] trial step (a ``lax.cond`` per step would run both branches
+  under ``vmap``).  ``dense_steps`` counts its steps, so the share the
+  compact path served is ``1 - dense_steps / greedy_steps``.
 * :func:`dagsa_schedule_batch` vmaps the whole greedy over a stacked fleet
-  of problems; ``backend="pallas"`` routes the per-step [M, N] candidate
-  solves through the :mod:`repro.kernels.bandwidth_solve` kernel.
+  of problems; ``backend="pallas"`` routes the per-step candidate solves
+  through the :mod:`repro.kernels.bandwidth_solve` kernel.
 """
 from __future__ import annotations
 
 from functools import partial
-from typing import Sequence
+from typing import NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +53,9 @@ from repro.core import bandwidth
 from repro.core.types import ScheduleResult, SchedulingProblem
 
 _BACKENDS = ("jax", "pallas")
+# Members a BS's compact trial row holds; with its candidate the row is
+# one 128-lane tile.
+_MEMBER_WIDTH = 127
 
 
 def _bs_times_with_candidate(coeff, tcomp, assign, bs_bw, cand,
@@ -55,8 +71,7 @@ def _bs_times_with_candidate(coeff, tcomp, assign, bs_bw, cand,
     if backend == "pallas":
         from repro.kernels.bandwidth_solve import bandwidth_solve
         trial = assign.T.at[jnp.arange(m), cand].set(True)     # [M, N]
-        tc = jnp.broadcast_to(tcomp[None, :], trial.shape)
-        return bandwidth_solve(coeff.T, tc, trial, bs_bw, method=method,
+        return bandwidth_solve(coeff.T, tcomp, trial, bs_bw, method=method,
                                iters=iters, lo=t_bs, interpret=interpret)
     if backend != "jax":
         raise ValueError(f"unknown backend {backend!r}; "
@@ -72,16 +87,58 @@ def _bs_times_with_candidate(coeff, tcomp, assign, bs_bw, cand,
                                                      cand, hints)
 
 
+def _member_times_with_candidate(members, coeff, tcomp, bs_bw, cand, t_bs,
+                                 method="newton", iters=None, backend="jax",
+                                 interpret=None):
+    """:func:`_bs_times_with_candidate` over each BS's member list.
+
+    ``members`` is ``(member_coeff, member_tcomp, count)``: [M, W] f32
+    planes, zero past each row's count, and [M] int32.  Trial row k is BS
+    k's members plus a last lane holding cand[k], so the solve reads
+    [M, W + 1] and no [M, N] temporary exists.
+    """
+    m_coeff, m_tcomp, count = members
+    m, w = m_coeff.shape
+    c = jnp.concatenate(
+        [m_coeff, coeff[cand, jnp.arange(m)].astype(jnp.float32)[:, None]],
+        axis=1)
+    tc = jnp.concatenate(
+        [m_tcomp, tcomp[cand].astype(jnp.float32)[:, None]], axis=1)
+    lane = jnp.arange(w + 1)[None, :]
+    trial = (lane < count[:, None]) | (lane == w)
+    if backend == "pallas":
+        from repro.kernels.bandwidth_solve import bandwidth_solve
+        return bandwidth_solve(c, tc, trial, bs_bw, method=method,
+                               iters=iters, lo=t_bs, interpret=interpret)
+    return jax.vmap(partial(bandwidth.bs_time, method=method, iters=iters))(
+        c, tc, trial, bs_bw, lo_hint=t_bs)
+
+
+class _Greedy(NamedTuple):
+    """The greedy's loop state, the same in the compact and dense loops."""
+    assign: jax.Array       # [N, M] bool
+    remaining: jax.Array    # [N] bool, not yet scheduled
+    t_star: jax.Array       # the round's threshold t*
+    t_bs: jax.Array         # [M] each BS's optimal time t_k^*
+    cand: jax.Array         # [M] each BS's best-channel remaining user
+    cand_val: jax.Array     # [M] its comparison value
+    t_with: jax.Array       # [M] t_k^* with the candidate added
+    key: jax.Array
+    steps: jax.Array        # int32 users the loop added
+
+
 @partial(jax.jit, static_argnames=("min_participants", "method", "iters",
                                    "backend", "interpret", "selection_block"))
 def _schedule(snr, coeff, tcomp, bs_bw, necessary, min_participants, key,
               method="newton", iters=None, backend="jax", interpret=None,
               selection_block=None, snr_scale=None):
     """The greedy: ``(assign, selected, user_bw, t_k, t_round,
-    greedy_steps)``, the last the int32 number of while-loop iterations,
-    one user added each (step 3 onwards).  Selection runs under
-    the ``dagsa.select`` scope and every Eq. (11) solve under
-    ``dagsa.eq11``, so a trace attributes their device time by name."""
+    greedy_steps, dense_steps)``, the last two int32: the while-loop
+    iterations, one user added each (step 3 onwards), and those of them
+    the dense [M, N] trial step took (all of them at ``N <= W``).
+    Selection runs under the ``dagsa.select`` scope and every Eq. (11)
+    solve under ``dagsa.eq11``, so a trace attributes their device time
+    by name."""
     n, m = snr.shape
     solve = partial(_bs_times_with_candidate, method=method, iters=iters,
                     backend=backend, interpret=interpret)
@@ -144,75 +201,144 @@ def _schedule(snr, coeff, tcomp, bs_bw, necessary, min_participants, key,
     def n_selected(assign):
         return jnp.sum(assign.any(axis=1))
 
-    def candidates(assign, remaining, t_bs):
-        """Best-channel remaining user per BS + its trial t_k^*."""
-        with jax.named_scope("dagsa.select"):
-            cand, cand_val = _cands(snr, remaining)           # [M], [M]
+    def dense_trials(s):
         with jax.named_scope("dagsa.eq11"):
-            t_with = solve(coeff, tcomp, assign, bs_bw, cand, t_bs=t_bs)
-        return cand, cand_val, t_with
+            return solve(coeff, tcomp, s.assign, bs_bw, s.cand, t_bs=s.t_bs)
 
-    cand0, cval0, t_with0 = candidates(assign0, remaining0, t_bs0)
+    with jax.named_scope("dagsa.select"):
+        cand0, cval0 = _cands(snr, remaining0)              # [M], [M]
+    state = _Greedy(assign0, remaining0, t_star0, t_bs0, cand0, cval0, None,
+                    key, jnp.int32(0))
 
-    def body(state):
-        (assign, remaining, t_star, t_bs, cand, cand_val, t_with, key,
-         steps) = state
-        has_cand = jnp.any(remaining)
-        feasible = (t_with <= t_star) & has_cand
+    def add_one(s):
+        """Steps 3-4 once: add the picked user and select each BS's next
+        candidate.  Returns the new state, whose ``t_with`` is still the
+        old one, the picked BS and user, and whether the user was added."""
+        has_cand = jnp.any(s.remaining)
+        feasible = (s.t_with <= s.t_star) & has_cand
         any_feasible = jnp.any(feasible)
 
         # pick the feasible BS whose candidate has the best channel; the
         # selection pass already produced each candidate's (masked,
         # dequantised) comparison value, == snr[cand, k] whenever any user
         # remains, so the greedy tie order matches the seed bit-for-bit
-        score = jnp.where(feasible, cand_val, -jnp.inf)
+        score = jnp.where(feasible, s.cand_val, -jnp.inf)
         k_greedy = jnp.argmax(score)
 
         # otherwise force-add to a random BS and raise the threshold (8h);
         # m == 1 short-circuits the draw (mirrors the host greedy: a
         # determined draw must not consume entropy)
+        key = s.key
         if m > 1:
             key, krand = jax.random.split(key)
             k_forced = jax.random.randint(krand, (), 0, m)
         else:
             k_forced = jnp.int32(0)
-        need_more = n_selected(assign) < min_participants
+        need_more = n_selected(s.assign) < min_participants
         k_star = jnp.where(any_feasible, k_greedy, k_forced)
-        i_star = cand[k_star]
+        i_star = s.cand[k_star]
         do_add = has_cand & (any_feasible | need_more)
 
-        new_assign = jnp.where(do_add, assign.at[i_star, k_star].set(True),
-                               assign)
-        new_remaining = jnp.where(do_add, remaining.at[i_star].set(False),
-                                  remaining)
+        new_assign = jnp.where(do_add, s.assign.at[i_star, k_star].set(True),
+                               s.assign)
+        new_remaining = jnp.where(do_add,
+                                  s.remaining.at[i_star].set(False),
+                                  s.remaining)
         # the accepted candidate evaluation IS the BS's new optimal time
-        new_t_bs = jnp.where(do_add, t_bs.at[k_star].set(t_with[k_star]),
-                             t_bs)
-        raised = jnp.maximum(t_star, t_with[k_star])
-        new_t_star = jnp.where(do_add & ~any_feasible, raised, t_star)
-        new_cand, new_cval, new_t_with = candidates(new_assign,
-                                                    new_remaining, new_t_bs)
-        return (new_assign, new_remaining, new_t_star, new_t_bs, new_cand,
-                new_cval, new_t_with, key, steps + 1)
+        new_t_bs = jnp.where(do_add,
+                             s.t_bs.at[k_star].set(s.t_with[k_star]), s.t_bs)
+        raised = jnp.maximum(s.t_star, s.t_with[k_star])
+        new_t_star = jnp.where(do_add & ~any_feasible, raised, s.t_star)
+        with jax.named_scope("dagsa.select"):
+            new_cand, new_cval = _cands(snr, new_remaining)
+        return (s._replace(assign=new_assign, remaining=new_remaining,
+                           t_star=new_t_star, t_bs=new_t_bs, cand=new_cand,
+                           cand_val=new_cval, key=key, steps=s.steps + 1),
+                k_star, i_star, do_add)
 
-    def cond(state):
-        assign, remaining, t_star, t_bs, cand, cand_val, t_with, key, _ = state
-        any_feasible = jnp.any((t_with <= t_star) & jnp.any(remaining))
-        need_more = n_selected(assign) < min_participants
-        return jnp.any(remaining) & (any_feasible | need_more)
+    def body(s):
+        s, *_ = add_one(s)
+        return s._replace(t_with=dense_trials(s))
+
+    def cond(s):
+        any_feasible = jnp.any((s.t_with <= s.t_star) & jnp.any(s.remaining))
+        need_more = n_selected(s.assign) < min_participants
+        return jnp.any(s.remaining) & (any_feasible | need_more)
 
     # cond holds only while a user remains and a BS takes one, so every
-    # iteration adds exactly one user: ``steps`` counts them
-    assign, *_, steps = jax.lax.while_loop(
-        cond, body,
-        (assign0, remaining0, t_star0, t_bs0, cand0, cval0, t_with0, key,
-         jnp.int32(0)))
+    # iteration of either loop adds exactly one user: ``steps`` counts them
+    width = _MEMBER_WIDTH
+    if n <= width:
+        state = jax.lax.while_loop(cond, body,
+                                   state._replace(t_with=dense_trials(state)))
+        compact_steps = 0
+    else:
+        member_trials = partial(_member_times_with_candidate, method=method,
+                                iters=iters, backend=backend,
+                                interpret=interpret)
+
+        def compact_body(carry):
+            s, (m_coeff, m_tcomp, count) = carry
+            s, k_star, i_star, do_add = add_one(s)
+            # cond keeps every count under the width: the slot exists
+            slot = count[k_star]
+            m_coeff = m_coeff.at[k_star, slot].set(
+                coeff[i_star, k_star].astype(jnp.float32))
+            m_tcomp = m_tcomp.at[k_star, slot].set(
+                tcomp[i_star].astype(jnp.float32))
+            count = count.at[k_star].add(do_add.astype(jnp.int32))
+            members = (m_coeff, m_tcomp, count)
+            with jax.named_scope("dagsa.eq11"):
+                t_with = member_trials(members, coeff, tcomp, bs_bw, s.cand,
+                                       s.t_bs)
+            return s._replace(t_with=t_with), members
+
+        def compact_cond(carry):
+            s, (_, _, count) = carry
+            return cond(s) & jnp.all(count < width)
+
+        members0 = _seed_members(coeff, tcomp, best_bs, necessary, width)
+        with jax.named_scope("dagsa.eq11"):
+            t_with0 = member_trials(members0, coeff, tcomp, bs_bw, cand0,
+                                    t_bs0)
+        # a BS past the width from step 1 has no compact trial: the dense
+        # trials replace them, in a loop of zero or one trips (lax.cond
+        # would run the dense solve on every decision under vmap), and
+        # the compact loop then takes no step
+        _, t_with0 = jax.lax.while_loop(
+            lambda c: c[0],
+            lambda c: (jnp.zeros((), bool), dense_trials(state)),
+            (jnp.any(members0[2] > width), t_with0))
+        state, _ = jax.lax.while_loop(
+            compact_cond, compact_body,
+            (state._replace(t_with=t_with0), members0))
+        compact_steps = state.steps
+        state = jax.lax.while_loop(cond, body, state)
 
     with jax.named_scope("dagsa.eq11"):
-        t_k, user_bw = bandwidth.solve_all(coeff, tcomp, assign, bs_bw,
+        t_k, user_bw = bandwidth.solve_all(coeff, tcomp, state.assign, bs_bw,
                                            method=method, iters=iters)
-    selected = assign.any(axis=1)
-    return assign, selected, user_bw, t_k, jnp.max(t_k), steps
+    selected = state.assign.any(axis=1)
+    return (state.assign, selected, user_bw, t_k, jnp.max(t_k), state.steps,
+            state.steps - compact_steps)
+
+
+def _seed_members(coeff, tcomp, best_bs, necessary, width):
+    """Step 1's member lists: each BS's necessary users, in user order, as
+    ``(member_coeff [M, W], member_tcomp [M, W], count [M])``.  A row
+    holds its first ``W`` users; ``count`` is the BS's full count."""
+    n, m = coeff.shape
+    on_bs = jnp.where(necessary, best_bs, m).astype(jnp.int32)  # m: none
+    on_sorted, order = jax.lax.sort(
+        (on_bs, jnp.arange(n, dtype=jnp.int32)), num_keys=1)
+    start = jnp.searchsorted(on_sorted, jnp.arange(m + 1, dtype=jnp.int32))
+    count = (start[1:] - start[:-1]).astype(jnp.int32)
+    lane = jnp.arange(width)[None, :]
+    user = order[jnp.minimum(start[:-1, None] + lane, n - 1)]    # [M, W]
+    member = lane < count[:, None]
+    m_coeff = jnp.where(member, coeff[user, jnp.arange(m)[:, None]], 0.0)
+    m_tcomp = jnp.where(member, tcomp[user], 0.0)
+    return (m_coeff.astype(jnp.float32), m_tcomp.astype(jnp.float32), count)
 
 
 def dagsa_schedule_jit(problem: SchedulingProblem, key: jax.Array,
@@ -278,7 +404,7 @@ def dagsa_schedule_batch(problems, keys: jax.Array, method: str = "newton",
         every array field) or a sequence of same-shape problems.
       keys: [F, 2] PRNG keys, one per problem (``jax.random.split``).
       method/iters: Eq. (11) solver knobs (safeguarded Newton by default).
-      backend: "jax" (vmapped scalar solver) or "pallas" (per-step [M, N]
+      backend: "jax" (vmapped scalar solver) or "pallas" (per-step
         candidate solves through the ``bandwidth_solve`` kernel AND
         streaming segmented-argmax selection through
         ``kernels.select_topk``, so no [N, M] selection temporaries).

@@ -14,7 +14,9 @@ optional ``lo`` row vector warm-starts the bracket (t_k^* is monotone
 nondecreasing in the scheduled set, so a greedy caller passes the previous
 per-BS time).
 
-Layout: coeff/tcomp/mask [K, U], bw/lo [K, 1].  A row of a million users
+Layout: coeff/tcomp/mask [K, U], bw/lo [K, 1]; a tcomp shared by every
+row (the greedy's dense trials) comes as one [1, U] row, so it is never
+broadcast to [K, U] in HBM.  A row of a million users
 does not fit VMEM, so the user axis streams through it: the grid is
 (row blocks, 1 + iters passes, user blocks), users innermost.  Pass 0
 accumulates the bracket (user count, sum c_i, max tcomp_i); pass p >= 1
@@ -57,7 +59,7 @@ def _fold_users(c_ref, t_ref, m_ref, step, acc, *, jb: int, n_users: int,
         cols = ((slice(None), pl.ds(off, chunk)) if chunk < block
                 else (slice(None), slice(None)))
         c = c_ref[cols].astype(jnp.float32)
-        tc = t_ref[cols].astype(jnp.float32)
+        tc = jnp.broadcast_to(t_ref[cols].astype(jnp.float32), c.shape)
         m = m_ref[cols].astype(jnp.float32)
         if n_users % block:
             user = (jb * block + off
@@ -176,7 +178,8 @@ def bandwidth_solve(coeff: jnp.ndarray, tcomp: jnp.ndarray,
                     row_block: int = DEFAULT_ROW_BLOCK,
                     iters: int | None = None, method: str = "newton",
                     interpret: bool | None = None) -> jnp.ndarray:
-    """coeff/tcomp/mask [K, U]; bw (and optional warm-start lo) [K] -> t* [K].
+    """coeff/mask [K, U], tcomp [K, U] or [U] (one row every solve shares);
+    bw (and optional warm-start lo) [K] -> t* [K].
 
     ``interpret=None`` auto-enables interpret mode off-TPU so the same entry
     point runs everywhere (CPU tests/benches vs real TPU lowering).
@@ -198,11 +201,15 @@ def bandwidth_solve(coeff: jnp.ndarray, tcomp: jnp.ndarray,
     lo2 = lo.astype(jnp.float32).reshape(-1, 1)
     plane = pl.BlockSpec((rb, ub), lambda r, p, j: (r, j))
     row = pl.BlockSpec((rb, 1), lambda r, p, j: (r, 0))
+    t_spec = plane
+    if tcomp.ndim == 1:           # shared: every row block reads the one row
+        tcomp = tcomp.reshape(1, u)
+        t_spec = pl.BlockSpec((1, ub), lambda r, p, j: (0, j))
     out = pl.pallas_call(
         functools.partial(_bw_kernel, iters=iters, method=method,
                           n_users=u, block=ub),
         grid=(pl.cdiv(k, rb), 1 + iters, pl.cdiv(u, ub)),
-        in_specs=[plane, plane, plane, row, row],
+        in_specs=[plane, t_spec, plane, row, row],
         out_specs=row,
         out_shape=jax.ShapeDtypeStruct((k, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((rb, 1), jnp.float32)] * 8,

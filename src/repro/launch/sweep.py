@@ -42,7 +42,8 @@ Wireless record schema (one dict per scenario, JSON list on stdout /
                                        #   the Eq. (8g) fairness monitor
      "curves": {"t_round_s": [R], "n_selected": [R],
                 "min_part_rate": [R],  # per-round means across seeds
-                "greedy_steps": [R]}}  # DAGSA while-loop iterations
+                "greedy_steps": [R],   # DAGSA while-loop iterations
+                "dense_steps": [R]}}   #   of them on the dense [M, N] trials
 
 Learning records add (see :func:`run_learning_sweep`):
 
@@ -179,7 +180,8 @@ def _one_cell(p: dict, key: jax.Array, cfg: WirelessConfig, n_rounds: int,
 
     This body carries the round step's span names by copy
     (:func:`repro.fl.rounds.make_round_step`), plus ``world.init``, and
-    returns the greedy's iteration count as ``greedy_steps``.
+    returns the greedy's iteration count as ``greedy_steps`` and those
+    of them its dense [M, N] trial step took as ``dense_steps``.
     """
     with jax.named_scope("world.init"):
         k_pos, k_bs, k_bw, k_aux, k_shadow, k_run = jax.random.split(key, 6)
@@ -228,12 +230,14 @@ def _one_cell(p: dict, key: jax.Array, cfg: WirelessConfig, n_rounds: int,
                 k_sched, backend=backend, selection_block=user_chunk,
                 snr_scale=snr_scale)
         selected, t_round = decision[1], decision[4]
+        *_, greedy_steps, dense_steps = decision
         counts = counts + selected.astype(counts.dtype)
         out = {
             "t_round": t_round,
             "n_selected": jnp.sum(selected).astype(jnp.float32),
             "min_part_rate": jnp.min(counts) / (r + 1.0),
-            "greedy_steps": decision[-1],
+            "greedy_steps": greedy_steps,
+            "dense_steps": dense_steps,
         }
         return (pos, aux, counts, key), out
 
@@ -290,6 +294,7 @@ def _wireless_records(group: list[tuple[int, ScenarioSpec]], outs: dict,
     n_sel = np.asarray(outs["n_selected"])
     min_pr = np.asarray(outs["min_part_rate"])
     steps = np.asarray(outs["greedy_steps"])
+    dense = np.asarray(outs["dense_steps"])
     records: dict[int, dict] = {}
     for i, (pos, spec) in enumerate(group):
         records[pos] = {
@@ -307,6 +312,7 @@ def _wireless_records(group: list[tuple[int, ScenarioSpec]], outs: dict,
                 "n_selected": n_sel[i].mean(axis=0).tolist(),
                 "min_part_rate": min_pr[i].mean(axis=0).tolist(),
                 "greedy_steps": steps[i].mean(axis=0).tolist(),
+                "dense_steps": dense[i].mean(axis=0).tolist(),
             },
         }
     return records

@@ -130,3 +130,113 @@ def test_jit_dagsa_vmappable():
     t_rounds = outs[4]
     assert t_rounds.shape == (4,)
     assert np.isfinite(np.asarray(t_rounds)).all()
+
+
+# ------------------------------------------- compact member-list trials --
+def _fleet_problem(seed, necessary_share):
+    """N=512 users on M=8 BSs, more users than the compact width holds;
+    ``necessary_share`` of them are Eq. (8g)-necessary (step 1)."""
+    from repro.core.types import MobilityState
+    n, m = 512, 8
+    cfg = WirelessConfig(n_users=n, n_bs=m, rho1=0.1, rho2=0.1)
+    k_pos, k_bs, k_nec, k_prob = jax.random.split(jax.random.PRNGKey(seed),
+                                                  4)
+    state = MobilityState(
+        user_pos=jax.random.uniform(k_pos, (n, 2)) * cfg.area_m,
+        bs_pos=jax.random.uniform(k_bs, (m, 2)) * cfg.area_m)
+    nec = jax.random.bernoulli(k_nec, necessary_share, (n,))
+    return channel.make_problem(k_prob, state, cfg,
+                                jnp.where(nec, 0.0, 10.0), 5)
+
+
+# name -> (seed, necessary share).  "open" never fills a BS past 7
+# members; "seeded" starts with up to 9 necessary users on a BS and ends
+# with 14; "open2" is another world like "open".
+FLEET = {"open": (0, 0.0), "seeded": (0, 0.06), "open2": (2, 0.0)}
+DENSE = 512          # a width >= N: the dense loop alone
+BACKENDS = pytest.mark.parametrize("backend", ["jax", "pallas"])
+
+
+@pytest.fixture(scope="module")
+def decide():
+    """``decide(backend, world, width)``: the greedy's outputs as numpy,
+    with the compact width patched to ``width``; memoised, since every
+    width is a compilation of its own."""
+    from repro.core import dagsa_jit
+    memo = {}
+
+    def run(backend, world, width):
+        if (backend, world, width) not in memo:
+            p = _fleet_problem(*FLEET[world])
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(dagsa_jit, "_MEMBER_WIDTH", width)
+                jax.clear_caches()
+                out = dagsa_jit._schedule(
+                    p.snr, p.coeff, p.tcomp, p.bs_bw, p.necessary,
+                    p.min_participants, jax.random.PRNGKey(7),
+                    backend=backend)
+                memo[backend, world, width] = [np.asarray(o) for o in out]
+            jax.clear_caches()
+        return memo[backend, world, width]
+    return run
+
+
+def _assert_same_decision(got, want):
+    np.testing.assert_array_equal(got[0], want[0])          # assign
+    np.testing.assert_array_equal(got[1], want[1])          # selected
+    np.testing.assert_allclose(got[4], want[4], rtol=1e-5)  # t_round
+    assert int(got[5]) == int(want[5])                      # greedy steps
+
+
+@BACKENDS
+@pytest.mark.parametrize("world", ["open", "seeded"])
+def test_compact_trials_decide_as_the_dense_mask(decide, backend, world):
+    _assert_same_decision(decide(backend, world, 127),
+                          decide(backend, world, DENSE))
+
+
+@BACKENDS
+def test_no_dense_step_when_no_bs_fills_the_width(decide, backend):
+    for world in ("open", "seeded"):
+        *_, steps, dense_steps = decide(backend, world, 127)
+        assert int(steps) > 0 and int(dense_steps) == 0
+        # the dense loop alone counts every step as dense
+        *_, steps, dense_steps = decide(backend, world, DENSE)
+        assert int(dense_steps) == int(steps)
+
+
+@BACKENDS
+def test_hand_over_to_the_dense_loop_keeps_the_decision(decide, backend):
+    got = decide(backend, "open", 2)
+    assert 0 < int(got[6]) < int(got[5])     # both loops took steps
+    _assert_same_decision(got, decide(backend, "open", DENSE))
+
+
+@BACKENDS
+def test_necessary_users_past_the_width_go_dense_from_the_start(decide,
+                                                                 backend):
+    got = decide(backend, "seeded", 4)        # up to 9 seeded on a BS
+    assert int(got[6]) == int(got[5]) > 0     # the compact loop took none
+    _assert_same_decision(got, decide(backend, "seeded", DENSE))
+
+
+@BACKENDS
+def test_batch_with_one_overflow_equals_single_calls(decide, backend,
+                                                     monkeypatch):
+    from repro.core import dagsa_jit
+    worlds = ["open", "seeded", "open2"]
+    singles = [decide(backend, w, 10) for w in worlds]
+    # only "seeded" (9 necessary users, 14 at the end) fills the width
+    assert [int(s[6]) > 0 for s in singles] == [False, True, False]
+    monkeypatch.setattr(dagsa_jit, "_MEMBER_WIDTH", 10)
+    jax.clear_caches()
+    try:
+        res = dagsa_jit.dagsa_schedule_batch(
+            [_fleet_problem(*FLEET[w]) for w in worlds],
+            jnp.stack([jax.random.PRNGKey(7)] * 3), backend=backend)
+    finally:
+        jax.clear_caches()
+    for i, single in enumerate(singles):
+        np.testing.assert_array_equal(np.asarray(res.assign[i]), single[0])
+        np.testing.assert_array_equal(np.asarray(res.selected[i]), single[1])
+        np.testing.assert_array_equal(np.asarray(res.t_round[i]), single[4])

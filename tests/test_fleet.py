@@ -132,6 +132,23 @@ def test_kernel_newton_matches_oracle():
                                rtol=1e-4, atol=1e-6)
 
 
+@pytest.mark.parametrize("u", [40, 16384 + 40], ids=["one-block", "ragged"])
+def test_kernel_shared_tcomp_row_equals_its_broadcast(u):
+    """A [U] tcomp, one row every solve shares, reads as the same row
+    broadcast to [K, U]: the same roots, bit for bit."""
+    rng = np.random.default_rng(6)
+    k = 5
+    coeff = jnp.asarray(rng.uniform(0.05, 2.0, (k, u)), jnp.float32)
+    tcomp = jnp.asarray(rng.uniform(0.05, 0.15, (u,)), jnp.float32)
+    mask = jnp.asarray(rng.random((k, u)) < 0.3)
+    bw = jnp.asarray(rng.uniform(0.5, 2.0, (k,)), jnp.float32)
+    shared = bandwidth_solve(coeff, tcomp, mask, bw, interpret=True)
+    full = bandwidth_solve(coeff, jnp.broadcast_to(tcomp, (k, u)), mask, bw,
+                           interpret=True)
+    np.testing.assert_array_equal(np.asarray(shared), np.asarray(full))
+    assert (np.asarray(shared) > 0).all()
+
+
 # ------------------------------------------------------- batched DAGSA ----
 def test_batch_matches_per_problem_loop():
     """dagsa_schedule_batch == per-problem dagsa_schedule_jit, same keys,

@@ -65,12 +65,14 @@ def _select(dtype, which):
     return fn, (snr, scale)
 
 
-def _bandwidth(k, u):
+def _bandwidth(k, u, coeff_dtype=jnp.bfloat16, tcomp_shape=None):
     def fn(c, t, m, bw, lo):
         return bandwidth_solve(c, t, m, bw, lo=lo, interpret=False)
-    # the greedy's trial solve: bf16 coefficients, [K, U] trial mask
-    return fn, (jax.ShapeDtypeStruct((k, u), jnp.bfloat16),
-                jax.ShapeDtypeStruct((k, u), jnp.float32),
+    # the greedy's trial solve: [K, U] coefficients (the bf16 plane, or the
+    # f32 member lists of the compact trials) and trial mask; the dense
+    # trials share one [U] tcomp row
+    return fn, (jax.ShapeDtypeStruct((k, u), coeff_dtype),
+                jax.ShapeDtypeStruct(tcomp_shape or (k, u), jnp.float32),
                 jax.ShapeDtypeStruct((k, u), jnp.bool_),
                 jax.ShapeDtypeStruct((k,), jnp.float32),
                 jax.ShapeDtypeStruct((k,), jnp.float32))
@@ -136,12 +138,21 @@ CASES = {
     "best_bs_argmax-1e6x100-bf16": lambda: _select(jnp.bfloat16, "best"),
     "best_bs_argmax-1e6x100-int8": lambda: _select(jnp.int8, "best"),
     "bandwidth_solve-8x50": lambda: _bandwidth(8, 50),
+    "bandwidth_solve-8x50-shared-tcomp": lambda: _bandwidth(8, 50,
+                                                            tcomp_shape=(50,)),
     "bandwidth_solve-100x1e6": lambda: _bandwidth(MEGA_M, MEGA_N),
+    "bandwidth_solve-100x128": lambda: _bandwidth(MEGA_M, 128, jnp.float32),
     "fedavg_reduce-fc1-50": _fedavg,
     "fedavg_segment_reduce-fc1-50x8": _fedavg_segment,
     "sparsify_quantize-int8-50x5e4": _sparsify_int8,
     "dagsa_decision-mega-fleet-pallas": _mega_fleet_decision,
 }
+
+# Leading kernel operands a case's program must hold: the decision solves
+# Eq. (11) over compact [M, 128] member lists, and keeps the dense [M, N]
+# continuation for a BS that fills its list.
+OPERANDS = {"dagsa_decision-mega-fleet-pallas": (
+    f"f32[{MEGA_M},128]", f"f32[{MEGA_M},{MEGA_N}]")}
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -151,8 +162,13 @@ def test_kernel_compiles_for_v5e(one_chip, case):
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
         shapes)
     compiled = jax.jit(fn).lower(*placed).compile()
-    assert "tpu_custom_call" in compiled.as_text(), (
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, (
         f"{case}: no Pallas kernel in the compiled program")
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    for shape in OPERANDS.get(case, ()):
+        assert any(f"operand_layout_constraints={{{shape}" in line
+                   for line in calls), f"{case}: no kernel call on {shape}"
     mem = compiled.memory_analysis()
     total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
              + mem.output_size_in_bytes)

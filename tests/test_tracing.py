@@ -89,13 +89,15 @@ def test_greedy_steps_count_the_users_the_loop_added(seed, selection_block):
     p = _problem(seed)
     args = (p.snr, p.coeff, p.tcomp, p.bs_bw, p.necessary,
             p.min_participants, jax.random.PRNGKey(seed + 10))
-    *res, steps = dagsa_jit._schedule(*args,
-                                      selection_block=selection_block)
+    *res, steps, dense_steps = dagsa_jit._schedule(
+        *args, selection_block=selection_block)
     n_selected = int(np.asarray(res[1]).sum())
     step1 = int(np.asarray(p.necessary).sum())
     assert 0 < step1 < n_selected
-    assert steps.dtype == jnp.int32
+    assert steps.dtype == dense_steps.dtype == jnp.int32
     assert int(steps) == n_selected - step1
+    # N = 120 is within the compact width: every step is a dense one
+    assert int(dense_steps) == int(steps)
 
 
 def test_sweep_records_carry_greedy_steps():
@@ -104,6 +106,7 @@ def test_sweep_records_carry_greedy_steps():
                           user_chunk=16)[0]
     steps = rec["curves"]["greedy_steps"]
     assert len(steps) == 2
+    assert rec["curves"]["dense_steps"] == steps     # N = 64: all dense
     assert all(0 <= s <= n for s, n in zip(steps,
                                            rec["curves"]["n_selected"]))
 
