@@ -20,10 +20,16 @@ Readings (each has its limit in ``bench/limits/<cell>.json``):
   norms it sees a change in the wrong direction, such as an Eq. (2) mean
   over half of the scheduled clients.
 
+A hierarchical world has two models, the global one and the edge models
+stacked by BS; each is compared on its own (the median leaf is its own),
+and a reading is the worse of the two.
+
 Read by ``bench/calibrate.py`` only, beside those (``extra``):
 ``update_dir_gap_leaf`` (the same distance by the worst moved leaf, on
 ``update_gap``'s scale), ``update_gap_median`` (the median leaf's gap of
-norms) and ``acc_gap`` (the largest gap of a round's test accuracy).
+norms), ``acc_gap`` (the largest gap of a round's test accuracy) and, in
+a hierarchical world, ``update_gap_global`` and ``update_dir_gap_global``
+(the global model's alone).
 """
 from __future__ import annotations
 
@@ -67,26 +73,41 @@ def schedule_gaps(got: list, ref: list) -> dict:
     }
 
 
+def models(out: dict) -> dict:
+    """An FL run's records, schedule counts and models as host leaves: the
+    global model and, in a hierarchical world, the edge models."""
+    edges = out.get("edge_params")
+    return {"records": out["records"], "counts": np.asarray(out["counts"]),
+            "params": host_tree(out["params"]),
+            "edge_params": None if edges is None else host_tree(edges)}
+
+
 def fl_readings(first: dict, ref: dict, world: dict,
                 extra: bool = False) -> dict:
-    """``first``: the program's first records and models (host leaves);
+    """``first``: the program's first records and models (:func:`models`);
     ``ref``: :func:`bench.reference.fl.follow`'s output."""
-    hier = world["aggregation"] == "hierarchical"
     base = host_tree(ref["params0"])
-    ref_models = host_tree(ref["edge_params"] if hier else ref["params"])
-    if hier:     # edge leaves are [M, ...]: the base is the shared start
-        base = [np.broadcast_to(b[None], r.shape)
-                for b, r in zip(base, ref_models)]
+    pairs = [(first["params"], host_tree(ref["params"]), base)]
+    if world["aggregation"] == "hierarchical":
+        edges = host_tree(ref["edge_params"])
+        # edge leaves are [M, ...]: their base is the shared start
+        pairs.append((first["edge_params"], edges,
+                      [np.broadcast_to(b[None], r.shape)
+                       for b, r in zip(base, edges)]))
+    gaps = [leaf_gaps(*p) for p in pairs]
+    dirs = [dir_gaps(*p) for p in pairs]
     out = schedule_gaps(first["records"], ref["records"])
-    gaps = leaf_gaps(first["models"], ref_models, base)
-    out["update_gap"] = float(np.max(gaps))
-    whole, per_leaf = dir_gaps(first["models"], ref_models, base)
-    out["update_dir_gap"] = whole
+    out["update_gap"] = max(float(np.max(g)) for g in gaps)
+    out["update_dir_gap"] = max(whole for whole, _ in dirs)
     out["selection_gap"] = float(np.sum(
         np.asarray(first["counts"]) != np.asarray(ref["counts"])))
     if extra:
-        out["update_dir_gap_leaf"] = float(np.max(per_leaf))
-        out["update_gap_median"] = float(np.median(gaps))
+        out["update_dir_gap_leaf"] = max(float(np.max(leaf))
+                                         for _, leaf in dirs)
+        out["update_gap_median"] = float(np.median(np.concatenate(gaps)))
         out["acc_gap"] = max(abs(g["test_acc"] - r["test_acc"]) for g, r in
                              zip(first["records"], ref["records"]))
+        if len(pairs) > 1:
+            out["update_gap_global"] = float(np.max(gaps[0]))
+            out["update_dir_gap_global"] = dirs[0][0]
     return out

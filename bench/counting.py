@@ -125,3 +125,24 @@ def roofline_seconds(flops: float, nbytes_: float, peaks: dict) -> float:
     """The least time the chip could take: the larger of the two bounds."""
     return max(flops / peaks["bf16_flops_per_s"],
                nbytes_ / peaks["hbm_bytes_per_s"])
+
+
+def roofline_share(ctx, kernels) -> float | None:
+    """The share of their roofline (%) that the calls of ``kernels``
+    reached in a traced window: each call's least time (its FLOPs and
+    bytes, counted once per call from the compiled program) summed over
+    its traced calls, over the calls' traced time.  A kernel that re-reads
+    its operands is counted once, so the share is a lower bound.  None
+    where the run was not traced or no call of ``kernels`` ran in it."""
+    if ctx.trace is None:
+        return None
+    least = spent = 0.0
+    for inst, info in ctx.kernel_calls.items():
+        if info["kernel"] not in kernels:
+            continue
+        n = ctx.trace.call_counts.get(inst, 0)
+        least += n * roofline_seconds(info["flops"], info["bytes"], ctx.peaks)
+        spent += ctx.trace.call_seconds.get(inst, 0.0)
+    if spent <= 0.0:
+        return None
+    return 100.0 * least / spent

@@ -3,7 +3,9 @@
 Each call runs the traffic's ``rounds_per_call`` rounds as one compiled
 ``lax.scan`` of ``make_round_step`` and brings the per-round records to
 the host.  Set-up builds one simulation from the seed and runs its first
-call, which compiles; those first rounds are what the reference follows.
+calls, the first of which compiles; those first rounds are what the
+reference follows.  They reach the first global sync of a hierarchical
+world (:func:`compared_rounds`), so that the comparison covers it.
 Route options (``fedavg_backend``) are passed while ``FLConfig`` still has
 the field, and dropped, with a line on stderr, once it has not.
 """
@@ -13,8 +15,6 @@ import dataclasses
 import math
 import sys
 
-import numpy as np
-
 from bench import compare
 from bench.harness import free_device
 from bench.lowering import Recorder
@@ -22,6 +22,30 @@ from bench.lowering import Recorder
 
 def build(config: dict, traffic: dict, seed: int):
     return FusedRounds(config, traffic, seed)
+
+
+def tiny(cell):
+    """The cell cut for a CPU rehearsal: 800 training images and 200 test
+    images, one local epoch at batch 4."""
+    cell.config.update(n_train=800, n_test=200, local_epochs=1,
+                       batch_size=4)
+    return cell
+
+
+def rehearsal_counters() -> dict:
+    """``counters()`` of three rounds at the cells' size: all 50 clients
+    trained, as ``compute="full"`` does, and about half of them kept."""
+    return {"rounds": 3, "n_selected": [50, 25, 26],
+            "n_trained": [50, 50, 50]}
+
+
+def compared_rounds(traffic: dict) -> int:
+    """The set-up's rounds that the reference follows: whole calls, as
+    many as reach round ``tau_global``, where a hierarchical world first
+    syncs its edges into the global model (one call where every round
+    syncs)."""
+    k = int(traffic["rounds_per_call"])
+    return k * -(-int(traffic["world"]["tau_global"]) // k)
 
 
 def program_seed(seed: int) -> int:
@@ -103,16 +127,15 @@ class FusedRounds:
             raise ValueError(f"scenario {world['scenario']!r} resolved to "
                              f"{got}, the traffic states {want}")
 
-    def _models(self) -> dict:
-        hier = self.traffic["world"]["aggregation"] == "hierarchical"
-        tree = self.sim.edge_params if hier else self.sim.params
-        return compare.host_tree(tree)
-
     def warm(self) -> None:
-        recs = self.sim.run(self.k, mode="fused")
-        self.first = {"records": [_rec(r) for r in recs],
-                      "models": self._models(),
-                      "counts": np.asarray(self.sim.part.counts)}
+        recs = []
+        for _ in range(compared_rounds(self.traffic) // self.k):
+            recs += self.sim.run(self.k, mode="fused")
+        hier = self.traffic["world"]["aggregation"] == "hierarchical"
+        self.first = compare.models({
+            "records": [_rec(r) for r in recs], "params": self.sim.params,
+            "edge_params": self.sim.edge_params if hier else None,
+            "counts": self.sim.part.counts})
 
     def call(self):
         recs = self.sim.run(self.k, mode="fused")
@@ -123,7 +146,8 @@ class FusedRounds:
 
     def counters(self) -> dict:
         return {"rounds": len(self.window),
-                "n_selected": [r["n_selected"] for r in self.window]}
+                "n_selected": [r["n_selected"] for r in self.window],
+                "n_trained": [r["n_trained"] for r in self.window]}
 
     def lowered(self):
         """The window's program, lowered again from its last call's own
@@ -138,7 +162,7 @@ class FusedRounds:
         from bench.reference import fl as ref_fl
         ref = ref_fl.follow(program_seed(self.seed),
                             reference_config(self.config),
-                            self.traffic["world"], self.k)
+                            self.traffic["world"], len(self.first["records"]))
         return compare.fl_readings(self.first, ref, self.traffic["world"],
                                    extra)
 
@@ -158,10 +182,12 @@ def controls(config: dict, traffic: dict, seed: int):
     planted faults in the program's place.  The control is the reference
     computed in bfloat16, the precision below the configuration's
     float32; the faults are the reference with Eq. (2) over only the
-    first half of the scheduled clients, and the reference's own records
+    first half of the scheduled clients, the reference's own records
     with each round's latency off by one part in a thousand (an answer
-    altered where it is produced).  A state left unchanged reads 1 in
-    ``update_gap`` and ``update_dir_gap`` and needs no run.  Beside them,
+    altered where it is produced), and, in a hierarchical world, the
+    reference with its global sync left out or weighting every edge
+    alike.  A state left unchanged reads 1 in ``update_gap`` and
+    ``update_dir_gap`` and needs no run.  Beside them,
     ``highest_in_place``: the reference with its products at HIGHEST in
     the place of the one at the configuration's precision, how far that
     step alone moves the readings."""
@@ -170,33 +196,27 @@ def controls(config: dict, traffic: dict, seed: int):
     from bench.reference import fl as ref_fl
     cfg = reference_config(config)
     world = traffic["world"]
-    k = int(traffic["rounds_per_call"])
+    n = compared_rounds(traffic)
     s = program_seed(seed)
-    hier = world["aggregation"] == "hierarchical"
-
-    def models(out):
-        return compare.host_tree(out["edge_params"] if hier
-                                 else out["params"])
-    ref = ref_fl.follow(s, cfg, world, k)
-    high = ref_fl.follow(s, cfg, world, k, matmul="highest")
-    first = {"records": high["records"], "models": models(high),
-             "counts": high["counts"]}
-    yield "highest_in_place", seed, compare.fl_readings(first, ref, world,
-                                                        True)
-    for kind, kw in (("control_bf16", {"dtype": jnp.bfloat16}),
-                     ("fault_half_batch", {"half_batch": True})):
-        alt = ref_fl.follow(s, cfg, world, k, **kw)
-        first = {"records": alt["records"], "models": models(alt),
-                 "counts": alt["counts"]}
-        yield kind, seed, compare.fl_readings(first, ref, world, True)
-    first = {"records": [dict(r, t_round=r["t_round"] * 1.001)
-                         for r in ref["records"]],
-             "models": models(ref), "counts": ref["counts"]}
+    ref = ref_fl.follow(s, cfg, world, n)
+    kinds = [("highest_in_place", {"matmul": "highest"}),
+             ("control_bf16", {"dtype": jnp.bfloat16}),
+             ("fault_half_batch", {"half_batch": True})]
+    if world["aggregation"] == "hierarchical":
+        kinds += [("fault_sync_skipped", {"sync": "skipped"}),
+                  ("fault_sync_equal", {"sync": "equal"})]
+    for kind, kw in kinds:
+        alt = compare.models(ref_fl.follow(s, cfg, world, n, **kw))
+        yield kind, seed, compare.fl_readings(alt, ref, world, True)
+    first = compare.models(ref)
+    first["records"] = [dict(r, t_round=r["t_round"] * 1.001)
+                        for r in ref["records"]]
     yield "fault_altered_answer", seed, compare.fl_readings(first, ref,
                                                             world, True)
 
 
 def _rec(r) -> dict:
     return {"t_round": float(r.t_round), "n_selected": int(r.n_selected),
-            "test_acc": float(r.test_acc)}
+            "test_acc": float(r.test_acc),
+            "n_trained": int(r.n_trained)}
 

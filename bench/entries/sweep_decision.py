@@ -29,6 +29,28 @@ def build(config: dict, traffic: dict, seed: int):
     return Decisions(config, traffic, seed)
 
 
+def tiny(cell):
+    """The cell cut for a CPU rehearsal: 20,000 users picking about 60, on
+    two of the cell's worlds, through the chunked jnp route, whose
+    decisions equal the Pallas route's (Pallas interpret mode is slow on
+    the CPU)."""
+    cell.config.update(n_users=20000, rho2=3e-3)
+    cell.traffic["options"]["backend"] = "jax"
+    cell.traffic["world_seeds"] = cell.traffic["world_seeds"][:2]
+    return cell
+
+
+def rehearsal_counters() -> dict:
+    """``counters()`` of one call at the cell's size: four decisions of
+    about 300 picks, with the greedy steps of the cell's four worlds
+    (PERF.md), none of them dense."""
+    return {"decisions": 4, "n_selected": [321, 309, 301, 340],
+            "greedy_steps": [351, 309, 301, 340], "dense_steps": [0, 0, 0, 0]}
+
+
+COUNTERS = ("greedy_steps", "dense_steps")    # the program's, per decision
+
+
 def wireless(config: dict) -> dict:
     keys = ("n_users", "n_bs", "area_m", "noise_dbm_mhz", "tx_dbm_mhz",
             "model_mbit", "bs_bandwidth_mhz", "tcomp_min_s", "tcomp_max_s",
@@ -82,9 +104,10 @@ class Decisions:
             raise ValueError(f"scenario {self.world['scenario']!r} moves at "
                              f"{rec.get('speed_mps')} m/s, the traffic states "
                              f"{self.world['speed_mps']}")
-        return {"world_seed": ws,
-                "t_round": float(rec["curves"]["t_round_s"][0]),
-                "n_selected": int(rec["curves"]["n_selected"][0])}
+        curves = rec["curves"]
+        return {"world_seed": ws, "t_round": float(curves["t_round_s"][0]),
+                "n_selected": int(curves["n_selected"][0]),
+                **{k: int(curves[k][0]) for k in COUNTERS}}
 
     def warm(self) -> None:
         self.first = self._decide(self.order[0])
@@ -95,8 +118,11 @@ class Decisions:
         return len(recs), sum(not math.isfinite(r["t_round"]) for r in recs)
 
     def counters(self) -> dict:
+        """The window's decisions, their picks, their greedy steps and the
+        dense ones among them."""
         return {"decisions": len(self.window),
-                "n_selected": [r["n_selected"] for r in self.window]}
+                **{k: [r[k] for r in self.window]
+                   for k in ("n_selected",) + COUNTERS}}
 
     def lowered(self):
         """The decision's program, lowered again from the window's last

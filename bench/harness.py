@@ -7,21 +7,29 @@ traffic mix.  Everything else is found by name:
   rounds per call and the route options;
 * ``bench/entries/<entry>.py``: ``build(config, traffic, seed)`` returns
   an object with ``warm()``, ``call() -> (units, failed)``,
-  ``counters()``, ``lowered()`` (the window's program lowered again from
-  its last call, or None), ``release()`` and ``check() -> {reading:
-  value}``; ``sound`` and ``controls`` give ``bench/calibrate.py`` its
-  readings;
+  ``counters()`` (the program's own counts over the window),
+  ``lowered()`` (the window's program lowered again from its last call,
+  or None), ``release()`` and ``check() -> {reading: value}``; ``sound``
+  and ``controls`` give ``bench/calibrate.py`` its readings; ``tiny(cell)``
+  cuts a cell of the entry to a size the CPU rehearses in seconds, and
+  ``rehearsal_counters()`` stands in for ``counters()`` where the
+  rehearsal reads every metric without a run;
 * ``bench/limits/<cell>.json``: the limit of each reading;
 * ``bench/metrics/<metric>.py``: ``read(ctx) -> float | None`` for every
-  metric of ``BENCHMARK.json``.
+  metric of ``BENCHMARK.json``;
+* an optional ``spans`` list in the configuration or traffic file: scopes
+  of the cell's program that the span vocabulary of ``bench/spans.py``
+  lacks.
 
 A run: build the cell's world from the seed, warm its shapes (set-up),
 call the entry back to back for at least ``seconds`` (or, traced, for the
 traffic's ``trace_calls``), read the device's peak memory, free the
 program, run the reference and compare.
 
-A traced run fails, with no result, where the yardstick cannot see what
-it reads: a kernel that the timed program lowers has no call in the
+A traced run also reduces the trace by span (``bench.spans.reduce``, the
+reduction ``python3 -m bench.spans`` prints), so that readers get device
+seconds by span.  It fails, with no result, where the yardstick cannot see
+what it reads: a kernel that the timed program lowers has no call in the
 trace, or a metric the cell lists reads nothing.  A kernel's roofline may
 read nothing only where the timed program lowers none of its kernels.
 """
@@ -53,8 +61,8 @@ def load_json(path: Path) -> dict:
         return json.load(f)
 
 
-def load_module(kind: str, name: str):
-    path = BENCH / kind / f"{name}.py"
+def load_module(kind: str, name: str, root: Path = ROOT):
+    path = root / "bench" / kind / f"{name}.py"
     spec = importlib.util.spec_from_file_location(
         f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
     if spec is None or not path.is_file():
@@ -75,6 +83,7 @@ class Cell:
     limits: dict
     e2e: list = field(default_factory=list)
     per_layer: list = field(default_factory=list)
+    root: Path = ROOT           # the checkout whose files the cell names
 
 
 def _reports(metric: dict, cell: str, e2e_names: set) -> bool:
@@ -101,7 +110,7 @@ def find_cell(name: str, root: Path = ROOT) -> Cell:
                  if _reports(m, name, e2e_names)]
     return Cell(name=name, chips=int(w["chips"]), config=config,
                 traffic=traffic, limits=limits, e2e=e2e,
-                per_layer=per_layer)
+                per_layer=per_layer, root=root)
 
 
 def free_device() -> None:
@@ -160,6 +169,33 @@ class Context:
     peaks: dict
     trace: object = None          # bench.trace.Summary in a traced run
     kernel_calls: dict = field(default_factory=dict)
+    spans: object = None          # bench.spans.Spans in a traced run
+
+    @property
+    def span_seconds(self) -> dict | None:
+        """Device seconds by span over the traced window, in the cell's
+        vocabulary (``python3 -m bench.spans --workload <cell>``)."""
+        return None if self.spans is None else self.spans.device_s
+
+    def span_total(self, names) -> float | None:
+        """Device seconds under the spans ``names`` in the traced window.
+        None where the run was not traced or its programs hold no span of
+        the vocabulary at all.  A program that holds spans but not each of
+        ``names``, or operations whose program the trace does not hold,
+        are errors: time would read as none."""
+        s = self.spans
+        if s is None or not s.present:
+            return None
+        if s.unresolved_s > 0:
+            raise RuntimeError(
+                f"{s.unresolved_s!r} device seconds of {self.cell.name}'s "
+                f"trace ran in no program it holds")
+        missing = [n for n in names if n not in s.present]
+        if missing:
+            raise RuntimeError(
+                f"the traced programs of {self.cell.name} hold no span "
+                f"{missing} (they hold {sorted(s.present)})")
+        return sum(s.device_s.get(n, 0.0) for n in names)
 
 
 class GcPauses:
@@ -191,13 +227,25 @@ def unseen_kernels(kernels, kernel_calls: dict, summary) -> list:
         if k in c.get("kernels", [c["kernel"]]))]
 
 
+def reduce_trace(xplane: str, cell: Cell, kernels, kernel_calls: dict,
+                 window_s: float | None):
+    """A traced window's ``bench.trace.Summary``, and its device seconds by
+    span of the cell's vocabulary as ``python3 -m bench.spans --workload
+    <cell>`` prints them (``bench.spans.Spans``)."""
+    from bench import spans
+    from bench import trace as tr
+    summary = tr.reduce(xplane, kernels, kernel_calls, window_s)
+    return summary, spans.reduce(xplane, spans.vocabulary(cell.config,
+                                                          cell.traffic))
+
+
 def read_metrics(specs: list, ctx: Context, kernels) -> dict:
     """Every listed metric's reading.  One that reads nothing fails the
     run, unless it is a kernel's roofline (its reader names ``KERNELS``)
     and the timed program, as lowered, holds none of its kernels."""
     metrics = {}
     for m in specs:
-        reader = load_module("metrics", m["name"])
+        reader = load_module("metrics", m["name"], ctx.cell.root)
         value = reader.read(ctx)
         own = getattr(reader, "KERNELS", None)
         if value is not None:
@@ -223,7 +271,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     log(f"compile cache: {use_compile_cache()}")
     clock = CompileClock()
     pauses = GcPauses()
-    entry = load_module("entries", cell.traffic["entry"]).build(
+    entry = load_module("entries", cell.traffic["entry"], cell.root).build(
         cell.config, cell.traffic, seed)
     entry.warm()
     setup_s = time.perf_counter() - t_start
@@ -273,7 +321,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     program_bytes = footprint(compiled) if compiled is not None else None
     log(f"device peak in use and reserved {peak} B; the timed program's "
         f"compiled footprint {program_bytes} B")
-    summary, kernel_calls = None, {}
+    summary, kernel_calls, by_span = None, {}, None
     if trace:
         from bench import counting
         from bench import trace as tr
@@ -283,8 +331,11 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         kernel_calls = counting.kernel_calls(compiled.as_text(), kernels)
         log(f"kernel calls of the compiled program: "
             f"{ {k: c['kernels'] for k, c in kernel_calls.items()} }")
-        summary = tr.reduce(tr.find_xplane(tdir), kernels,
-                            kernel_calls, window_s)
+        summary, by_span = reduce_trace(tr.find_xplane(tdir), cell, kernels,
+                                        kernel_calls, window_s)
+        log(f"device seconds by span {by_span.device_s}; spans in the "
+            f"traced programs {sorted(by_span.present)}; of operations no "
+            f"traced program names {by_span.unresolved_s!r}")
         unseen = unseen_kernels(kernels, kernel_calls, summary)
         if unseen:
             mine = sorted(i for i, c in kernel_calls.items()
@@ -315,7 +366,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     ctx = Context(cell=cell, setup_s=setup_s, window_s=window_s,
                   units=units, peak_bytes=peak, counters=counters,
                   peaks=device_peaks(dev.device_kind) if trace else {},
-                  trace=summary, kernel_calls=kernel_calls)
+                  trace=summary, kernel_calls=kernel_calls, spans=by_span)
     metrics = read_metrics(cell.per_layer if trace else cell.e2e, ctx,
                            kernels)
     device = {"platform": dev.platform, "kind": dev.device_kind,

@@ -6,6 +6,11 @@ its operand and result bytes over the HBM bandwidth, both counted once per
 call from the call's shapes in the compiled program; the share is the
 calls' least time over their traced time.  A kernel that re-reads its
 operands is counted once, so the share is a lower bound.
+
+Since each greedy step solves its trials over the BSs' compact member
+lists, a call reads about 150 KB: the share is per call and bound by
+the call's latency, not the chip's bandwidth.  The Eq. (11) layer's time
+is ``eq11_ms_per_pass.decision``'s.
 """
 from bench import counting
 
@@ -13,16 +18,4 @@ KERNELS = ('_bw_kernel',)
 
 
 def read(ctx):
-    if ctx.trace is None:
-        return None
-    least = spent = 0.0
-    for inst, info in ctx.kernel_calls.items():
-        if info["kernel"] not in KERNELS:
-            continue
-        n = ctx.trace.call_counts.get(inst, 0)
-        least += n * counting.roofline_seconds(info["flops"], info["bytes"],
-                                               ctx.peaks)
-        spent += ctx.trace.call_seconds.get(inst, 0.0)
-    if spent <= 0.0:
-        return None
-    return 100.0 * least / spent
+    return counting.roofline_share(ctx, KERNELS)

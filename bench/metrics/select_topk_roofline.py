@@ -13,16 +13,4 @@ KERNELS = ('_select_kernel', '_rowmax_kernel')
 
 
 def read(ctx):
-    if ctx.trace is None:
-        return None
-    least = spent = 0.0
-    for inst, info in ctx.kernel_calls.items():
-        if info["kernel"] not in KERNELS:
-            continue
-        n = ctx.trace.call_counts.get(inst, 0)
-        least += n * counting.roofline_seconds(info["flops"], info["bytes"],
-                                               ctx.peaks)
-        spent += ctx.trace.call_seconds.get(inst, 0.0)
-    if spent <= 0.0:
-        return None
-    return 100.0 * least / spent
+    return counting.roofline_share(ctx, KERNELS)

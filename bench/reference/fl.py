@@ -18,14 +18,17 @@ from bench.reference.world import EngineWorld
 
 def follow(seed: int, cfg: dict, world: dict, n_rounds: int,
            dtype=jnp.float32, half_batch: bool = False,
-           matmul: str | None = None) -> dict:
+           matmul: str | None = None, sync: str = "weighted") -> dict:
     """Records of the first ``n_rounds`` rounds, the models after them and
     how often each user was scheduled.
 
     Matrix products run at the configuration's ``matmul`` precision unless
-    ``matmul`` names another.  ``dtype`` and ``half_batch`` exist for the
-    control and the planted fault: a bfloat16 data plane, or an Eq. (2)
-    mean over only the first half of the scheduled clients.
+    ``matmul`` names another.  ``dtype``, ``half_batch`` and ``sync`` exist
+    for the control and the planted faults: a bfloat16 data plane, an
+    Eq. (2) mean over only the first half of the scheduled clients, or a
+    hierarchical world whose edges never sync into the global model
+    (``sync="skipped"``) or sync with equal weights in place of the data
+    each aggregated (``"equal"``).
     """
     matmul = matmul or cfg["matmul"]
     env = EngineWorld(seed, cfg)
@@ -61,8 +64,9 @@ def follow(seed: int, cfg: dict, world: dict, n_rounds: int,
             edge = cnn.edge_fedavg(edge, clients, w_nm, dtype=dtype,
                                    matmul=matmul)
             edge_w = edge_w + jnp.sum(w_nm, axis=0)
-            if (r + 1) % world["tau_global"] == 0:
-                params = cnn.edge_mix(params, edge, edge_w, matmul=matmul)
+            if (r + 1) % world["tau_global"] == 0 and sync != "skipped":
+                mix_w = jnp.ones_like(edge_w) if sync == "equal" else edge_w
+                params = cnn.edge_mix(params, edge, mix_w, matmul=matmul)
                 edge = jax.tree.map(
                     lambda g, e: jnp.broadcast_to(g[None], e.shape),
                     params, edge)

@@ -1,14 +1,18 @@
 """The program's named spans, read from a ``--trace 1`` run's profiler trace.
 
-    python3 -m bench.spans .bench_traces/<cell>-<seed>
+    python3 -m bench.spans .bench_traces/<cell>-<seed> [--workload <cell>]
 
 prints device seconds by span, idle seconds by host span and what is left
-without a span.
+without a span.  With ``--workload`` the vocabulary is the cell's (see
+:func:`vocabulary`), as the harness reads it in a traced run.
 
 The program names its phases with ``jax.named_scope`` (device spans) and
 ``jax.profiler.TraceAnnotation`` (host spans).  The vocabulary is copied
 here, not imported, so that a change to the program cannot move the
-yardstick.
+yardstick.  ``SPANS`` is the base vocabulary; a cell adds the scopes of
+its own program (a client model's layers, say) as a ``spans`` list in its
+configuration or traffic file, so that their time is not booked under
+the span that calls them.
 
 * A device span is a path component of a compiled instruction's
   ``op_name`` metadata (a transform wraps the scopes inside it:
@@ -46,11 +50,21 @@ _WRAP = re.compile(r"^(?:\w+\()*|\)*$")
 _PROGRAM = re.compile(r"\((\d+)\)$")
 
 
-def innermost(op_name: str) -> str:
-    """The innermost span of the vocabulary in an ``op_name`` path."""
+def vocabulary(*files: dict) -> tuple:
+    """``SPANS`` and the names the ``spans`` lists of ``files`` (a cell's
+    configuration and traffic) add to it."""
+    out = list(SPANS)
+    for f in files:
+        out += [n for n in f.get("spans", ()) if n not in out]
+    return tuple(out)
+
+
+def innermost(op_name: str, spans=SPANS) -> str:
+    """The innermost span of the vocabulary ``spans`` in an ``op_name``
+    path."""
     for part in reversed(op_name.split("/")):
         name = _WRAP.sub("", part)
-        if name in SPANS:
+        if name in spans:
             return name
     return NO_SPAN
 
@@ -197,9 +211,11 @@ def _split_gap(g0: int, g1: int, spans) -> dict:
     return out
 
 
-def reduce(path: str) -> Spans:
-    """Device seconds by span, idle seconds by host span and the spans the
-    traced programs hold, from the trace at ``path``."""
+def reduce(path: str, spans=SPANS) -> Spans:
+    """Device seconds by span of the vocabulary ``spans``, idle seconds by
+    host span and the spans the traced programs hold, from the trace at
+    ``path``.  ``python3 -m bench.spans`` and the harness's traced runs
+    both read a trace with this."""
     from jax.profiler import ProfileData
     with open(path, "rb") as f:
         raw = f.read()
@@ -236,14 +252,14 @@ def reduce(path: str) -> Spans:
             elif bare:
                 unnamed += secs
                 bare_ops.add(inst)
-            span = innermost(op_name)
+            span = innermost(op_name, spans)
             device_s[span] = device_s.get(span, 0.0) + secs
             mine = ops.setdefault(span, {})
             mine[inst] = mine.get(inst, 0.0) + secs
     if not busy:
         raise ValueError(f"no device operation in {path}")
-    present = {innermost(o) for pid in ran for o, _ in op_names.get(pid, {})
-               .values()} - {NO_SPAN}
+    present = {innermost(o, spans) for pid in ran
+               for o, _ in op_names.get(pid, {}).values()} - {NO_SPAN}
     intervals = [(e.start_ns, e.end_ns, e.name) for p in planes
                  if p.name.startswith("/host:") for ln in p.lines
                  for e in ln.events if e.name in HOST_SPANS]
@@ -295,15 +311,20 @@ def report(s: Spans, top: int = 5) -> list[str]:
 
 
 def main(argv: list[str]) -> int:
-    if len(argv) != 1:
+    if len(argv) not in (1, 3) or argv[1:2] not in ([], ["--workload"]):
         print("usage: python3 -m bench.spans <trace directory or "
-              ".xplane.pb>", file=sys.stderr)
+              ".xplane.pb> [--workload <cell>]", file=sys.stderr)
         return 2
+    names = SPANS
+    if len(argv) == 3:
+        from bench import harness
+        cell = harness.find_cell(argv[2])
+        names = vocabulary(cell.config, cell.traffic)
     path = argv[0]
     if not path.endswith(".xplane.pb"):
         path = trace.find_xplane(path)
     print(path)
-    for line in report(reduce(path)):
+    for line in report(reduce(path, names)):
         print(line)
     return 0
 

@@ -8,36 +8,11 @@ from bench import harness
 
 
 def tiny_cell(name: str, root=harness.ROOT) -> harness.Cell:
-    """The cell as committed, with its world cut for the CPU: 800 training
-    images, one local epoch at batch 4; or 20,000 users picking about 60,
-    on two of the cell's worlds.
-    The control-plane cell takes the chunked jnp route, whose decisions
-    equal the Pallas route's, because Pallas interpret mode is slow here."""
+    """The cell as committed, with its world cut for the CPU by its own
+    entry's ``tiny``."""
     cell = harness.find_cell(name, root)
-    if cell.traffic["entry"] == "fl_fused":
-        cell.config.update(n_train=800, n_test=200, local_epochs=1,
-                           batch_size=4)
-    else:
-        cell.config.update(n_users=20000, rho2=3e-3)
-        cell.traffic["options"]["backend"] = "jax"
-        cell.traffic["world_seeds"] = cell.traffic["world_seeds"][:2]
-    return cell
-
-
-def hfl_cell() -> harness.Cell:
-    """The hierarchical high-mobility traffic on the paper's configuration,
-    cut like ``tiny_cell``: the reference's hierarchical path is kept
-    tested while its chip cell waits (PERF.md, Open questions)."""
-    b = harness.ROOT / "bench"
-    config = harness.load_json(b / "configs" / "paper-cnn-n50.json")
-    config.update(n_train=800, n_test=200, local_epochs=1, batch_size=4)
-    spec = harness.load_json(harness.ROOT / "BENCHMARK.json")
-    return harness.Cell(
-        name="paper-cnn.hfl-high-mobility", chips=1, config=config,
-        traffic=harness.load_json(b / "traffic" / "hfl-high-mobility.json"),
-        limits=harness.load_json(b / "limits" / "paper-cnn.sync.json"),
-        e2e=[m for m in spec["end_to_end"]
-             if "paper-cnn.sync" in m.get("workloads", ["paper-cnn.sync"])])
+    return harness.load_module("entries", cell.traffic["entry"],
+                               cell.root).tiny(cell)
 
 
 def run_tiny(cell: harness.Cell, seed: int = 7, seconds: float = 0.1):

@@ -19,7 +19,7 @@ import pytest
 
 from bench import compare, harness
 from bench.reference import fl as ref_fl
-from bench.tests.conftest import hfl_cell, run_tiny, tiny_cell
+from bench.tests.conftest import run_tiny, tiny_cell
 
 
 def _fails(readings: dict, limits: dict) -> list:
@@ -30,21 +30,17 @@ def _fails(readings: dict, limits: dict) -> list:
                                   "paper-cnn.hfl-high-mobility"])
 def test_bf16_reference_in_the_programs_place_reads_far_above_the_program(
         name):
-    cell = hfl_cell() if "hfl" in name else harness.find_cell(name)
+    cell = harness.find_cell(name)
     cell.config.update(n_train=1600, n_test=200, local_epochs=2,
                        batch_size=4)
     program = run_tiny(cell, seed=11)["checks"]
     entry = harness.load_module("entries", "fl_fused")
     cfg = entry.reference_config(cell.config)
     world = cell.traffic["world"]
-    k = int(cell.traffic["rounds_per_call"])
-    ref = ref_fl.follow(11, cfg, world, k)
-    alt = ref_fl.follow(11, cfg, world, k, dtype=jnp.bfloat16)
-    hier = world["aggregation"] == "hierarchical"
-    first = {"records": alt["records"], "counts": alt["counts"],
-             "models": compare.host_tree(
-                 alt["edge_params"] if hier else alt["params"])}
-    readings = compare.fl_readings(first, ref, world)
+    n = entry.compared_rounds(cell.traffic)
+    ref = ref_fl.follow(11, cfg, world, n)
+    alt = ref_fl.follow(11, cfg, world, n, dtype=jnp.bfloat16)
+    readings = compare.fl_readings(compare.models(alt), ref, world)
     assert readings["update_gap"] > 10 * program["update_gap"]["value"], \
         (readings, program)
 

@@ -2,13 +2,14 @@
 and sees ``correct`` come out false, once for each fault a cell can have:
 a step that returns its state unchanged, half of the scheduled clients
 left out of the Eq. (2) mean, and an answer (the round's latency) altered
-by one percent where the scheduler produces it.  All cells take one chip, so no exchange
+by one percent where the scheduler produces it; in the hierarchical cell
+also a global sync left out.  All cells take one chip, so no exchange
 between chips exists to leave out."""
 import jax
 import jax.numpy as jnp
 import pytest
 
-from bench.tests.conftest import hfl_cell, run_tiny, tiny_cell
+from bench.tests.conftest import run_tiny, tiny_cell
 
 SYNC, HFL, DECISION = ("paper-cnn.sync", "paper-cnn.hfl-high-mobility",
                        "mega-fleet.dagsa")
@@ -51,8 +52,18 @@ def altered_answer(monkeypatch, cell):
 
     def altered(*a, **k):
         out = orig(*a, **k)
-        return tuple(out[:4]) + (out[4] * 1.01,)
+        return tuple(out[:4]) + (out[4] * 1.01,) + tuple(out[5:])
     monkeypatch.setattr(dagsa_jit, "_schedule", altered)
+
+
+def sync_skipped(monkeypatch, cell):
+    from repro.fl import rounds
+    orig = rounds.hierarchical_round
+
+    def never(*a, **k):
+        k["tau_global"] = 10**6
+        return orig(*a, **k)
+    monkeypatch.setattr(rounds, "hierarchical_round", never)
 
 
 @pytest.fixture
@@ -65,12 +76,13 @@ def fresh_traces():
 @pytest.mark.parametrize("cell,fault", [
     (SYNC, unchanged), (SYNC, half_batch), (SYNC, altered_answer),
     (HFL, unchanged), (HFL, half_batch), (HFL, altered_answer),
+    (HFL, sync_skipped),
     (DECISION, altered_answer)],
     ids=lambda x: x if isinstance(x, str) else x.__name__)
 def test_fault_makes_the_run_incorrect(cell, fault, monkeypatch,
                                        fresh_traces):
     fault(monkeypatch, cell)
-    res = run_tiny(hfl_cell() if cell == HFL else tiny_cell(cell))
+    res = run_tiny(tiny_cell(cell))
     assert res["correct"] is False
     failing = [n for n, c in res["checks"].items()
                if not c["value"] <= c["limit"]]

@@ -1,5 +1,6 @@
 """The span reduction, on synthetic traces with known answers, on a
 program compiled here and on the small trace recorded on a TPU v5e."""
+import re
 from pathlib import Path
 
 import pytest
@@ -300,3 +301,54 @@ def test_existing_readings_of_the_recorded_trace_are_unchanged():
         pytest.approx(15.316738639422343)
     assert read("idle_share.learning") == pytest.approx(99.99430892325803)
     assert read("mfu.learning") == pytest.approx(4.232036436203666)
+
+
+def test_a_cells_spans_list_attributes_a_new_scope(tmp_path):
+    """A scope the base vocabulary lacks is booked under its caller's span;
+    a cell that lists it in its ``spans`` gets its time apart, and the rest
+    reduces as under the base vocabulary."""
+    step = {"main": dict(STEP["main"], **{
+        "fusion.1": "jit(step)/while/body/round.sgd/lm.attn/dot"})}
+    path = _write(tmp_path, _xspace({7: ("jit_step", step)}, OPS[:5],
+                                    MODULES[:1], HOST))
+    base = spans.reduce(path)
+    names = spans.vocabulary({"spans": ["lm.attn", "round.sgd"]}, {})
+    assert names == spans.SPANS + ("lm.attn",)
+    own = spans.reduce(path, names)
+    assert base.device_s["round.sgd"] == pytest.approx(4000e-9)
+    assert own.device_s["lm.attn"] == pytest.approx(3000e-9)
+    assert own.device_s["round.sgd"] == pytest.approx(1000e-9)
+    assert "lm.attn" in own.present and "lm.attn" not in base.present
+    rest = {k: v for k, v in own.device_s.items()
+            if k not in ("lm.attn", "round.sgd")}
+    assert rest == {k: v for k, v in base.device_s.items()
+                    if k != "round.sgd"}
+    assert spans.vocabulary({}, {}) == spans.SPANS
+
+
+def _printed_device_seconds(out: str) -> dict:
+    line = next(ln for ln in out.splitlines()
+                if ln.startswith("device seconds by span"))
+    pairs = (item.rsplit(" (", 1)[0].rsplit(" ", 1)
+             for item in re.split(r"%\), ", line.split(": ", 1)[1]))
+    return {name: float(v) for name, v in pairs}
+
+
+@pytest.mark.parametrize("recorded", [True, False],
+                         ids=["tpu-trace", "synthetic"])
+def test_the_harness_reads_what_the_command_prints(recorded, tmp_path,
+                                                   capsys):
+    """``ctx.span_seconds`` of a traced run is ``bench.spans.reduce``'s, in
+    the cell's vocabulary: what the command prints for the same trace."""
+    path = str(DATA / "small.xplane.pb") if recorded else _write(
+        tmp_path, _xspace({7: ("jit_step", STEP), 9: ("jit_other", OTHER)},
+                          OPS, MODULES, HOST))
+    cell = harness.find_cell("paper-cnn.sync")
+    _, by_span = harness.reduce_trace(path, cell, [], {}, None)
+    ctx = harness.Context(cell=cell, setup_s=1.0, window_s=1.0, units=1,
+                          peak_bytes=1, counters={}, peaks={},
+                          spans=by_span)
+    assert spans.main([path, "--workload", cell.name]) == 0
+    assert _printed_device_seconds(capsys.readouterr().out) == \
+        ctx.span_seconds
+    assert spans.main([path, "--workload"]) == 2
